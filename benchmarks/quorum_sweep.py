@@ -47,8 +47,7 @@ from typing import List, Tuple
 
 import jax
 
-# Join a multi-process grid BEFORE anything touches the jax backend: the
-# repro imports below create module-level arrays (engine.BIG), and both
+# Join a multi-process grid BEFORE anything touches the jax backend: both
 # the gloo CPU-collectives selection and jax.distributed.initialize only
 # take effect pre-backend.  No-op without the REPRO_* launch env.
 if os.environ.get("REPRO_COORDINATOR"):
@@ -314,6 +313,8 @@ if __name__ == "__main__":
                          "on an explicit global trial mesh — honored even "
                          "with a single device")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     if args.shard:
         # Grid membership was established at import (see top of module);
         # the explicit mesh pins the sweep to ALL global devices and is

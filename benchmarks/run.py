@@ -226,7 +226,7 @@ def multihost_benches(quick: bool):
                 2, 4, os.path.join(td, "p2x4.npz"), trials=trials)
             single = distributed.run_stream_layout(
                 1, 8, os.path.join(td, "p1x8.npz"), trials=trials)
-    except (NotImplementedError, RuntimeError) as e:
+    except NotImplementedError as e:      # the platform lacks gloo
         print(f"# stream.multihost skipped: {type(e).__name__}: "
               f"{str(e).splitlines()[0]}")
         return []
@@ -243,24 +243,31 @@ def multihost_benches(quick: bool):
     return rows
 
 
-def frontier_benches(quick: bool):
-    """Mixed-family Pareto frontier (DESIGN.md §8) on an n=12 cluster:
-    grid systems over the 3x4 factorization (plus narrower embeds),
-    weighted voting, and the three cardinality landmarks, all in ONE mask
-    batch — the general masked stream path, since a mixed batch carries no
-    "q" specialization — scored by one ``fast_path_stream`` + one
-    ``race_stream`` compile and reduced by the dominance kernel."""
+def mixed_family(n: int = 12):
+    """Grid systems over the 3x4 factorization (plus narrower embeds),
+    weighted voting, and the three cardinality landmarks as ONE mask batch
+    — the general masked stream path, since a mixed batch carries no "q"
+    specialization, and the only batch the fused megakernel serves."""
     from repro.core.quorum import QuorumSpec
-    from repro.frontier import families, score_systems
-    from repro.montecarlo import engine
+    from repro.frontier import families
 
-    n = 12
-    members = (
+    return (
         [families.Member(f"card.{t}", s) for t, s in
          (("headline", QuorumSpec.paper_headline(n)),
           ("fast_paxos", QuorumSpec.fast_paxos(n)),
           ("majority", QuorumSpec.majority_fast(n)))]
         + families.grid_family(n) + families.weighted_family(n))
+
+
+def frontier_benches(quick: bool):
+    """Mixed-family Pareto frontier (DESIGN.md §8) on an n=12 cluster
+    (``mixed_family``) scored by one ``fast_path_stream`` + one
+    ``race_stream`` compile and reduced by the dominance kernel."""
+    from repro.frontier import score_systems
+    from repro.montecarlo import engine
+
+    n = 12
+    members = mixed_family(n)
     trials = 131_072 if quick else 2_000_000
 
     t0 = dict(engine.TRACE_COUNTS)
@@ -502,7 +509,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro import compile_cache
     from repro.montecarlo import engine
+    compile_cache.enable()
 
     metrics, sections = {}, {}
     t0 = time.time()

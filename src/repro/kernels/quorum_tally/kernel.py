@@ -200,7 +200,8 @@ def _select_sat(x, w, t, k: int, big):
     every saturable row has crossed, so the result equals the full-sort
     ``engine._sat_time`` — bit-identical when weights are integral (exact
     f32 partial sums; the jnp path's cumsum is then the same sequence).
-    Unreached rows keep the ``big`` sentinel.  Returns the min over rows.
+    Unreached rows keep the ``big`` sentinel.  Returns the min over rows
+    as a ``(BS, 1)`` column.
     """
     bs, n_pad = x.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (bs, n_pad), 1)
@@ -218,7 +219,7 @@ def _select_sat(x, w, t, k: int, big):
         sat = jnp.where(newly, cur, sat)
         done = done | newly
         x = jnp.where(iota == first, jnp.inf, x)         # extract the lane
-    return sat.min(axis=-1)                              # (BS,)
+    return sat.min(axis=-1, keepdims=True)               # (BS, 1)
 
 
 def _stream_kernel(votes_ref, val_ref, arr_ref, cls_ref, w1_ref, t1_ref,
@@ -242,6 +243,11 @@ def _stream_kernel(votes_ref, val_ref, arr_ref, cls_ref, w1_ref, t1_ref,
     Outputs are revisited across the s grid dimension (index map pins them
     to block m), so the kernel initializes at s == 0 and accumulates after
     — the whole chunk reduces without leaving VMEM.
+
+    Per-trial quantities stay ``(BS, 1)`` columns and every reduction runs
+    over the sublane axis in f32 (exact: a block holds far fewer than 2^24
+    trials): Mosaic lays 1-D vectors out in a way its reductions cannot
+    re-tile, and a 1-D-to-scalar sum is refused on the TPU.
     """
     from repro.montecarlo.streaming import bucket_index
     s = pl.program_id(1)
@@ -250,7 +256,7 @@ def _stream_kernel(votes_ref, val_ref, arr_ref, cls_ref, w1_ref, t1_ref,
     votes = votes_ref[...]                               # (BS, n_pad) int32
     w2f = w2f_ref[0]                                     # (G_pad, n_pad) f32
     t2f = t2f_ref[0]                                     # (1, G_pad) f32
-    valid = valid_ref[...][0] != 0                       # (BS,) bool
+    valid = valid_ref[...] != 0                          # (BS, 1) bool
     bs, n_pad = votes.shape
 
     # masked tally: smallest value id saturating any fast row (else V).
@@ -260,14 +266,14 @@ def _stream_kernel(votes_ref, val_ref, arr_ref, cls_ref, w1_ref, t1_ref,
         wsum = jax.lax.dot_general(hit, w2f, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
         best = jnp.where(wsum >= t2f, v, best)           # (BS, G_pad)
-    best = best.min(axis=-1)                             # (BS,)
+    best = best.min(axis=-1, keepdims=True)              # (BS, 1)
     reached = best < n_values
     widx = jnp.clip(best, 0, n_values - 1)
 
     # winner's raw per-value 2b arrival lanes: static one-hot gather over K.
     win_x = val_ref[:, 0:n_pad]
     for k in range(1, n_values):
-        win_x = jnp.where((widx == k)[:, None],
+        win_x = jnp.where(widx == k,
                           val_ref[:, k * n_pad:(k + 1) * n_pad], win_x)
 
     t_fast = _select_sat(win_x, w2f, t2f, k2f, big)
@@ -283,23 +289,24 @@ def _stream_kernel(votes_ref, val_ref, arr_ref, cls_ref, w1_ref, t1_ref,
     decided = fast | recb
 
     # block-local histogram: one-hot bucket compare, summed over the block.
-    idx = bucket_index(lat, precision)                   # (BS,)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (votes.shape[0],
-                                                 hist_ref.shape[-1]), 1)
-    onehot = ((lanes == idx[:, None]) & decided[:, None]).astype(jnp.int32)
-    hist_blk = onehot.sum(axis=0)[None, :]               # (1, bins_pad)
+    idx = bucket_index(lat, precision)                   # (BS, 1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bs, hist_ref.shape[-1]), 1)
+    onehot = ((lanes == idx) & decided).astype(jnp.float32)
+    hist_blk = onehot.sum(axis=0, keepdims=True).astype(jnp.int32)
 
     f32 = jnp.float32
+    colsum = lambda x: x.astype(f32).sum(axis=0, keepdims=True)   # (1, 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape, 1)
     stat_blk = jnp.where(
-        lane == 0, fast.sum().astype(f32),
-        jnp.where(lane == 1, recb.sum().astype(f32),
-                  jnp.where(lane == 2, undb.sum().astype(f32),
+        lane == 0, colsum(fast),
+        jnp.where(lane == 1, colsum(recb),
+                  jnp.where(lane == 2, colsum(undb),
                             jnp.where(lane == 3,
-                                      jnp.where(decided, lat, 0.0).sum(),
+                                      colsum(jnp.where(decided, lat, 0.0)),
                                       jnp.where(lane == 4,
                                                 jnp.where(decided, lat,
-                                                          -jnp.inf).max(),
+                                                          -jnp.inf).max(
+                                                    axis=0, keepdims=True),
                                                 0.0)))))
 
     @pl.when(s == 0)
@@ -393,7 +400,7 @@ def stream_tally_decide_hist(votes: jax.Array, val_arr: jax.Array,
     w1_p, t1_p, g1_pad = pad_masks(w1, t1)
     w2c_p, t2c_p, g2c_pad = pad_masks(w2c, t2c)
     w2f_p, t2f_p, g2f_pad = pad_masks(w2f, t2f)
-    valid_p = jnp.zeros((1, s_pad), jnp.int32).at[0, :S].set(
+    valid_p = jnp.zeros((s_pad, 1), jnp.int32).at[:S, 0].set(
         valid.astype(jnp.int32))
 
     hist, stats = pl.pallas_call(
@@ -413,20 +420,24 @@ def stream_tally_decide_hist(votes: jax.Array, val_arr: jax.Array,
             pl.BlockSpec((1, 1, g2c_pad), lambda m, s: (m, 0, 0)),
             pl.BlockSpec((1, g2f_pad, n_pad), lambda m, s: (m, 0, 0)),
             pl.BlockSpec((1, 1, g2f_pad), lambda m, s: (m, 0, 0)),
-            pl.BlockSpec((1, bs), lambda m, s: (0, s)),
+            pl.BlockSpec((bs, 1), lambda m, s: (s, 0)),
         ],
+        # (M, 1, ·) outputs with a squeezed leading block dim: the block's
+        # last two dims then equal the array's, as Mosaic's tiling requires
+        # (a (1, b_pad) block over an (M, b_pad) array is refused).
         out_specs=[
-            pl.BlockSpec((1, b_pad), lambda m, s: (m, 0)),
-            pl.BlockSpec((1, LANE), lambda m, s: (m, 0)),
+            pl.BlockSpec((None, 1, b_pad), lambda m, s: (m, 0, 0)),
+            pl.BlockSpec((None, 1, LANE), lambda m, s: (m, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((M, b_pad), jnp.int32),
-            jax.ShapeDtypeStruct((M, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1, b_pad), jnp.int32),
+            jax.ShapeDtypeStruct((M, 1, LANE), jnp.float32),
         ],
         interpret=interpret,
     )(votes_p, val_p, arr_p, cls_p, w1_p, t1_p, w2c_p, t2c_p, w2f_p, t2f_p,
       valid_p)
-    return hist[:, :bins], {
+    hist, stats = hist[:, 0, :bins], stats[:, 0]
+    return hist, {
         "n_fast": stats[:, 0].astype(jnp.int32),
         "n_recovery": stats[:, 1].astype(jnp.int32),
         "n_undecided": stats[:, 2].astype(jnp.int32),

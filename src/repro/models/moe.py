@@ -32,8 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.parallel.sharding import (active_mesh, constrain, shard_map,
-                                     spec_for)
+from repro.parallel.sharding import active_mesh, constrain, spec_for
 
 from .layers import apply_mlp, dense_init, init_mlp
 # NOTE: no fsdp_use() here — the expert FFN runs inside shard_map
@@ -145,7 +144,7 @@ def moe_block(cfg: ArchConfig, p: Dict, x: jax.Array) -> jax.Array:
             y = _moe_shard(cfg, pl_, xb, ridx, n_shards, capacity)
             return jax.lax.psum(y, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=tuple(specs),
             out_specs=batch_spec,

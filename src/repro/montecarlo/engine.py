@@ -59,7 +59,7 @@ from repro.core.quorum import QuorumMasks, QuorumSpec
 from . import latency as lat_mod
 from .latency import LOST_MS, default_delay
 
-BIG = jnp.float32(LOST_MS)
+BIG = LOST_MS          # a Python float: importing the engine touches no device
 # latencies at or beyond this are "never happened" (lost-message sentinel
 # arithmetic); shared with scenarios.py so both layers classify identically
 UNDECIDED_MS = LOST_MS / 2

@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.parallel import sharding as psharding
+
 from .latency import (CrashedDelay, LossyDelay, PROPOSAL, delay_from_config,
                       delay_to_config)
 
@@ -192,7 +194,8 @@ class MarkovRegimes:
             ).astype(jnp.int32)
             return z_next, z
 
-        _, zs = jax.lax.scan(step, jnp.int32(self.start),
+        z0 = psharding.vary_like(jnp.int32(self.start), key)
+        _, zs = jax.lax.scan(step, z0,
                              jnp.arange(n_epochs, dtype=jnp.int32))
         return zs
 

@@ -448,7 +448,10 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
 
     oh = (vkey[:, None] == jnp.arange(V, dtype=jnp.int32)[None, :]
           ).astype(jnp.float32)                          # (C, V) valid only
-    Fsum = jnp.einsum("cj,cv->jv", win, oh)              # (k2f, V)
+    # HIGHEST: on the TPU the default f32 matmul rounds its operands to
+    # bf16, which would put a ~1e-3 relative error on the latency sums.
+    hi = jax.lax.Precision.HIGHEST
+    Fsum = jnp.einsum("cj,cv->jv", win, oh, precision=hi)    # (k2f, V)
     SFsum = jnp.flip(jnp.cumsum(jnp.flip(Fsum, 1), axis=1), 1)
     sum_fast = SFsum[q2f - 1, q2f]                       # (M,)
 
@@ -478,7 +481,8 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
     n_und = rec_rows[:, B]
     n_rec = hist_rec.sum(axis=-1)
 
-    Rsum = jnp.einsum("cp,cv->pv", jnp.where(dec, t_rec, 0.0), oh)
+    Rsum = jnp.einsum("cp,cv->pv", jnp.where(dec, t_rec, 0.0), oh,
+                      precision=hi)
     CRsum = jnp.cumsum(Rsum, axis=1)
     sum_rec = CRsum[pair_of_m, q2f - 1]
 
@@ -598,8 +602,9 @@ def _regime_device_stream(key, table, offsets, delay, trials, regimes, *,
         occ = occ + jnp.stack([s.sum() for s in sel]).astype(jnp.int32)
         return (occ, states), None
 
-    carry0 = (jnp.zeros((r,), jnp.int32),
-              tuple(StreamSummary.zeros(m, precision) for _ in range(r)))
+    carry0 = psharding.vary_like(
+        (jnp.zeros((r,), jnp.int32),
+         tuple(StreamSummary.zeros(m, precision) for _ in range(r))), key)
     (occ, states), _ = jax.lax.scan(body, carry0,
                                     jnp.arange(n_chunks, dtype=jnp.int32))
     return RegimeStreamSummary(
@@ -673,7 +678,7 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
                                       recovery=recovery)
                 state = state.update(out, valid)
             return state, None
-        state0 = StreamSummary.zeros(m, precision)
+        state0 = psharding.vary_like(StreamSummary.zeros(m, precision), key)
         state, _ = jax.lax.scan(body, state0,
                                 jnp.arange(n_chunks, dtype=jnp.int32))
         return state
@@ -706,8 +711,9 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
             lambda: device_stream(key=k_d, table=table, layout=layout,
                                   offsets=offsets, delay=delay, trials=t_d,
                                   regimes=regimes),
-            lambda: (StreamSummary.zeros(m, precision) if regimes is None
-                     else _regime_zeros(regimes, m, precision)))
+            lambda: psharding.vary_like(
+                StreamSummary.zeros(m, precision) if regimes is None
+                else _regime_zeros(regimes, m, precision), k_d))
         if regimes is not None:
             # per-regime slices merge exactly like plain summaries (their
             # leaves just carry a leading R axis); occupancy is an exact
@@ -719,7 +725,7 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
                 by_regime=state.by_regime.axis_merge(psharding.TRIAL_AXIS))
         return state.axis_merge(psharding.TRIAL_AXIS)
 
-    return psharding.shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P()),
         out_specs=P())(key, table, layout, offsets, delay, trials, regimes)

@@ -77,9 +77,8 @@ class DistInfo:
 
 
 def _backend_already_up() -> bool:
-    """True when an XLA backend client exists (best effort, version-tolerant
-    — pinned jax 0.4.x keeps the attribute, and a miss only degrades the
-    error message, never correctness)."""
+    """True when an XLA backend client exists (best effort: a miss only
+    degrades the error message, never correctness)."""
     try:
         from jax._src import xla_bridge
         return bool(getattr(xla_bridge, "_backends", None))
@@ -179,7 +178,8 @@ def launch_local(num_processes: int, devices_per_process: int,
     seeing ``devices_per_process`` forced host devices.
 
     Every process gets ``REPRO_COORDINATOR`` (a free localhost port),
-    ``REPRO_NUM_PROCESSES`` and ``REPRO_PROCESS_ID``, plus ``XLA_FLAGS``
+    ``REPRO_NUM_PROCESSES`` and ``REPRO_PROCESS_ID``, ``JAX_PLATFORMS=cpu``
+    (children never take an accelerator), plus ``XLA_FLAGS``
     rewritten to ``--xla_force_host_platform_device_count=D`` — the command
     itself must call ``initialize()`` before using jax.  Returns the
     captured stdout+stderr of each process (index-ordered); raises
@@ -218,6 +218,9 @@ def _launch_once(num_processes: int, devices_per_process: int,
            if not f.startswith("--xla_force_host_platform_device_count")]
     xla.append(f"--xla_force_host_platform_device_count={devices_per_process}")
     base["XLA_FLAGS"] = " ".join(xla)
+    # a forced-host-device CPU layout by design: children never take an
+    # accelerator (the parent may hold it, and one chip serves one process)
+    base["JAX_PLATFORMS"] = "cpu"
     base["PYTHONPATH"] = os.pathsep.join(
         p for p in (_src_root(), base.get("PYTHONPATH", "")) if p)
 
@@ -390,7 +393,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     mesh = psharding.trial_mesh()
     ndev = mesh.shape[psharding.TRIAL_AXIS]
-    f = psharding.shard_map(
+    f = jax.shard_map(
         lambda x: jax.lax.psum(
             jnp.asarray(jax.lax.axis_index(psharding.TRIAL_AXIS), jnp.int32),
             psharding.TRIAL_AXIS),

@@ -26,24 +26,24 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.6: top-level, check_vma kwarg
-    from jax import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KWARG = "check_vma"
-except ImportError:                     # jax 0.4.x: experimental, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KWARG = "check_rep"
-
 AxisSpec = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map (the replication-check kwarg was renamed
-    between jax 0.4 and 0.6).  Shared by the MoE expert parallelism
-    (``models/moe.py``) and the Monte-Carlo trial sharding
-    (``montecarlo/streaming.py``)."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SHARD_MAP_CHECK_KWARG: check_vma})
+def vary_like(tree, ref):
+    """Mark every leaf of ``tree`` as varying over the same manual mesh
+    axes as ``ref``.
+
+    Inside ``jax.shard_map(check_vma=True)`` a value built from constants
+    (a ``StreamSummary.zeros`` scan carry, a cond branch that returns
+    zeros) is device-invariant, while one derived from a per-device key is
+    varying; ``lax.scan`` and ``lax.cond`` require the two to match.
+    Outside ``shard_map`` ``ref`` varies over nothing and ``tree`` is
+    returned unchanged."""
+    axes = tuple(jax.typeof(ref).vma)
+    if not axes:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.pcast(x, axes, to="varying"), tree)
 
 
 # Mesh axis name for the Monte-Carlo trial dimension.  Distinct from the

@@ -97,6 +97,9 @@ def main(argv=None) -> int:
     _add_query_flags(p)
 
     args = ap.parse_args(argv)
+    if args.cmd in ("serve", "plan"):
+        from repro import compile_cache
+        compile_cache.enable()
 
     if args.cmd == "serve":
         from .service import DEFAULT_PORT, PlannerServer

@@ -289,11 +289,11 @@ def test_summarize_fixed_seed_regression_anchor():
                       jnp.array([0.0, 0.25]), n=11, k_proposers=2,
                       samples=20_000)
     s = engine.summarize(out)
-    assert float(s["p50_ms"][0]) == pytest.approx(1.22011, rel=1e-3)
-    assert float(s["recovery_rate"][0]) == pytest.approx(0.01645, rel=1e-3)
-    assert float(out["latency_ms"][0, 0]) == pytest.approx(1.258696,
+    assert float(s["p50_ms"][0]) == pytest.approx(1.22053, rel=1e-3)
+    assert float(s["recovery_rate"][0]) == pytest.approx(0.01725, rel=1e-3)
+    assert float(out["latency_ms"][0, 0]) == pytest.approx(1.232813,
                                                            rel=1e-5)
-    assert float(out["latency_ms"][0, 1]) == pytest.approx(1.37547,
+    assert float(out["latency_ms"][0, 1]) == pytest.approx(1.6301,
                                                            rel=1e-5)
 
 

@@ -35,10 +35,10 @@ EXAMPLES = os.path.join(os.path.dirname(__file__), "..", "examples",
 # drift means the regime key domain, the epoch->trial mapping, or the
 # per-regime scatter changed.
 REGIME_ANCHOR = {
-    'occupancy': [89760, 0, 10240],
-    'n_fast': 94869,
-    'n_recovery': 1502,
-    'n_undecided': 3629,
+    'occupancy': [100000, 0, 0],
+    'n_fast': 98331,
+    'n_recovery': 1669,
+    'n_undecided': 0,
     'p50_ms': 1.22746,
 }
 
