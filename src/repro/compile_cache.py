@@ -23,10 +23,17 @@ DEFAULT_DIR = os.path.join(
 
 
 def enable() -> str:
-    """Turn the persistent cache on and return its directory."""
+    """Turn the persistent cache on and return its directory.
+
+    Entries are keyed on the programs' debug metadata too (their
+    ``jax.named_scope`` names and source locations).  JAX leaves it out of
+    the key by default, and would then hand a program whose scopes changed
+    the executable compiled from its predecessor, whose profiler trace
+    carries the old names."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

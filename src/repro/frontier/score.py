@@ -142,42 +142,57 @@ def score_systems(systems: Sequence, *,
     ``recovery`` selects the collision-recovery rule priced by the race
     pass (``engine.RECOVERY_MODES``); ``p_recovery`` is rule-invariant (the
     entry condition is), but the tail axis re-prices q2c vs q2f.
+
+    Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``)
+    split each call: ``repro.score`` around it all; ``repro.score.table``
+    (holding ``repro.score.masks``, the host-side mask encoding) for the
+    mask table; ``repro.stream.fast_path`` and ``repro.stream.race`` for the
+    two stream dispatches; ``repro.score.readback`` for the reads that wait
+    on the device; ``repro.score.frontier`` for fault tolerance and the
+    Pareto mask.
     """
-    masks, native, n = _as_masks(systems, n)
-    labels = tuple(m.label or f"system{i}" for i, m in enumerate(masks))
-    table = engine.build_mask_table(masks)
-    axes = tuple(axes) if axes is not None else default_axes(precision,
-                                                             trials)
+    span = jax.profiler.TraceAnnotation
+    with span("repro.score", systems=len(systems), trials=trials, seed=seed):
+        with span("repro.score.table"):
+            with span("repro.score.masks"):
+                masks, native, n = _as_masks(systems, n)
+            labels = tuple(m.label or f"system{i}"
+                           for i, m in enumerate(masks))
+            table = engine.build_mask_table(masks)
+        axes = tuple(axes) if axes is not None else default_axes(precision,
+                                                                 trials)
 
-    key = jax.random.PRNGKey(seed)
-    k_fast, k_race = jax.random.split(key)
-    offsets = delta_ms * jnp.arange(k_proposers, dtype=jnp.float32)
+        key = jax.random.PRNGKey(seed)
+        k_fast, k_race = jax.random.split(key)
+        offsets = delta_ms * jnp.arange(k_proposers, dtype=jnp.float32)
 
-    fast = streaming.fast_path_stream(k_fast, table, delay, n=n,
-                                      trials=trials, chunk=chunk,
-                                      precision=precision, shard=shard,
-                                      k_max=k_max, regimes=regimes)
-    race = streaming.race_stream(k_race, table, offsets, delay, n=n,
-                                 k_proposers=k_proposers, trials=trials,
-                                 chunk=chunk, precision=precision,
-                                 use_kernel=use_kernel, shard=shard,
-                                 k_max=k_max, regimes=regimes,
-                                 recovery=recovery)
+        fast = streaming.fast_path_stream(k_fast, table, delay, n=n,
+                                          trials=trials, chunk=chunk,
+                                          precision=precision, shard=shard,
+                                          k_max=k_max, regimes=regimes)
+        race = streaming.race_stream(k_race, table, offsets, delay, n=n,
+                                     k_proposers=k_proposers, trials=trials,
+                                     chunk=chunk, precision=precision,
+                                     use_kernel=use_kernel, shard=shard,
+                                     k_max=k_max, regimes=regimes,
+                                     recovery=recovery)
 
-    fast_p50 = np.asarray(fast.quantile(0.5), np.float64)
-    race_p999 = np.asarray(race.quantile(0.999), np.float64)
-    p_rec = (np.asarray(race.n_recovery, np.float64)
-             / np.maximum(np.asarray(race.n_trials, np.float64), 1.0))
-    ft = [_fault_tolerance(s, m) for s, m in zip(native, masks)]
-    values = np.stack([
-        fast_p50,
-        race_p999,
-        p_rec,
-        np.array([f["steady_state_fast"] for f in ft], np.float64),
-        np.array([f["phase1"] for f in ft], np.float64),
-        np.array([f["phase2_classic"] for f in ft], np.float64),
-    ], axis=1)
-
-    return FrontierResult(labels=labels, axes=axes, values=values,
-                          mask=pareto_mask(values, axes),
-                          streams={"fast": fast, "race": race})
+        with span("repro.score.readback"):
+            fast_p50 = np.asarray(fast.quantile(0.5), np.float64)
+            race_p999 = np.asarray(race.quantile(0.999), np.float64)
+            p_rec = (np.asarray(race.n_recovery, np.float64)
+                     / np.maximum(np.asarray(race.n_trials, np.float64),
+                                  1.0))
+        with span("repro.score.frontier"):
+            ft = [_fault_tolerance(s, m) for s, m in zip(native, masks)]
+            values = np.stack([
+                fast_p50,
+                race_p999,
+                p_rec,
+                np.array([f["steady_state_fast"] for f in ft], np.float64),
+                np.array([f["phase1"] for f in ft], np.float64),
+                np.array([f["phase2_classic"] for f in ft], np.float64),
+            ], axis=1)
+            return FrontierResult(labels=labels, axes=axes, values=values,
+                                  mask=pareto_mask(values, axes),
+                                  streams={"fast": fast, "race": race})

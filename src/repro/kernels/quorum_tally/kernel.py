@@ -434,6 +434,7 @@ def stream_tally_decide_hist(votes: jax.Array, val_arr: jax.Array,
             jax.ShapeDtypeStruct((M, 1, LANE), jnp.float32),
         ],
         interpret=interpret,
+        name="stream_tally_decide_hist",
     )(votes_p, val_p, arr_p, cls_p, w1_p, t1_p, w2c_p, t2c_p, w2f_p, t2f_p,
       valid_p)
     hist, stats = hist[:, 0, :bins], stats[:, 0]

@@ -45,6 +45,12 @@ device memory use the chunked streaming drivers in
 ``repro.montecarlo.streaming`` (``race_stream`` / ``fast_path_stream`` /
 ``classic_path_stream``), which reduce each chunk into a fixed-size
 ``StreamSummary`` and shard the trial axis over devices.
+
+Device code carries ``jax.named_scope`` names, one per layer of a trial:
+``repro.sample`` (delay draws) and ``repro.decide`` (tallies, presorts,
+quorum saturations); ``streaming`` adds ``repro.sketch`` and
+``repro.merge``.  They change only HLO metadata, and let a profiler trace
+split device time by layer.
 """
 from __future__ import annotations
 
@@ -201,6 +207,7 @@ def saturation_depths(table: Dict[str, jax.Array]) -> Tuple[int, int, int]:
     return tuple(min(n, max(1, k)) for k in ks)
 
 
+@jax.named_scope("repro.decide")
 def _topk_ascending(x: jax.Array, k: Optional[int]):
     """Smallest-k ascending prefix of a stable sort over the last axis, plus
     the matching permutation prefix.  ``k`` of None (or >= n) falls back to
@@ -218,6 +225,7 @@ def _topk_ascending(x: jax.Array, k: Optional[int]):
     return -neg, idx.astype(jnp.int32)
 
 
+@jax.named_scope("repro.decide")
 def _sorted_prefix(x: jax.Array, k: Optional[int]) -> jax.Array:
     """Values-only ``_topk_ascending`` (lets XLA skip the permutation when a
     lowering consumes only order statistics)."""
@@ -226,6 +234,7 @@ def _sorted_prefix(x: jax.Array, k: Optional[int]) -> jax.Array:
     return -jax.lax.top_k(-x, k)[0]
 
 
+@jax.named_scope("repro.decide")
 def _kth(sorted_x: jax.Array, k: jax.Array) -> jax.Array:
     """k-th order statistic (1-indexed, traced k) from a presorted last axis."""
     idx = jnp.clip(k - 1, 0, sorted_x.shape[-1] - 1).astype(jnp.int32)
@@ -233,6 +242,7 @@ def _kth(sorted_x: jax.Array, k: jax.Array) -> jax.Array:
     return jnp.take_along_axis(sorted_x, idx, axis=-1)[..., 0]
 
 
+@jax.named_scope("repro.decide")
 def _counts_winner(votes: jax.Array, k_proposers: int, use_kernel: bool):
     """(S, n) votes -> ((S, K) counts, (S,) winner, (S,) max count).
 
@@ -270,6 +280,7 @@ def _check_recovery(recovery: str) -> None:
                          f"pick one of {RECOVERY_MODES}")
 
 
+@jax.named_scope("repro.sample")
 def _draw_race(key: jax.Array, offsets: jax.Array, delay, *, n: int,
                k_proposers: int, samples: int,
                recovery: str = "coordinated") -> Dict:
@@ -367,6 +378,7 @@ def _sample_race(key: jax.Array, offsets: jax.Array, delay, *, n: int,
 # Cardinality specialization: k-th-order-statistic gathers.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("repro.decide")
 def _win_sorted(draws: Dict) -> jax.Array:
     """(S, n) presorted 2b arrivals of each sample's winning value.  In the
     cardinality path the winner (max vote count) is system-independent, so
@@ -376,6 +388,7 @@ def _win_sorted(draws: Dict) -> jax.Array:
         axis=1)[:, 0, :]
 
 
+@jax.named_scope("repro.decide")
 def _decide(draws: Dict, win_sorted: jax.Array, q1: jax.Array, q_rec: jax.Array,
             q2f: jax.Array) -> Dict[str, jax.Array]:
     """Apply one (traced) threshold triple to presorted draws: gathers only.
@@ -408,6 +421,7 @@ def _decide(draws: Dict, win_sorted: jax.Array, q1: jax.Array, q_rec: jax.Array,
 # General path: arbitrary quorum systems as masked saturations (DESIGN.md §2).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("repro.decide")
 def _sat_time(sorted_x: jax.Array, perm: jax.Array, w: jax.Array,
               t: jax.Array) -> jax.Array:
     """Earliest instant some quorum row's masked arrival indicator saturates.
@@ -436,6 +450,7 @@ def _sat_time(sorted_x: jax.Array, perm: jax.Array, w: jax.Array,
     return tt.min(axis=0)
 
 
+@jax.named_scope("repro.decide")
 def _masked_vote_winner(votes: jax.Array, mask_table: Dict[str, jax.Array],
                         k_proposers: int, use_kernel: bool):
     """Per-sample-per-system fast-quorum vote check: which value (if any)
@@ -462,6 +477,7 @@ def _masked_vote_winner(votes: jax.Array, mask_table: Dict[str, jax.Array],
     return winner, reached
 
 
+@jax.named_scope("repro.decide")
 def _decide_masked(draws: Dict, masks: Dict[str, jax.Array],
                    winner: jax.Array, reached_votes: jax.Array,
                    rec_phase: str = "p2c") -> Dict[str, jax.Array]:
@@ -582,6 +598,7 @@ def race(key: jax.Array, table, offsets: jax.Array, delay=None, *, n: int,
                  samples=samples, use_kernel=use_kernel, recovery=recovery)
 
 
+@jax.named_scope("repro.sample")
 def _fast_path_draws(key: jax.Array, delay, n: int,
                      samples: int) -> jax.Array:
     """(S, n) conflict-free client -> acceptor -> learner path times, lost
@@ -628,6 +645,7 @@ def fast_path(key: jax.Array, table, delay=None, *, n: int,
     return _fast_path(key, table, delay, n=n, samples=samples)
 
 
+@jax.named_scope("repro.sample")
 def _classic_path_draws(key: jax.Array, delay, n: int, samples: int):
     """((S,) client->leader hop, (S, n) leader round-trip times); shared by
     the materializing and streamed classic-path lowerings."""
@@ -647,13 +665,14 @@ def _classic_path_outcomes(key: jax.Array, table: Dict[str, jax.Array],
         delay = default_delay()
     k2c = k_sat[1] if k_sat is not None else None
     d0, path = _classic_path_draws(key, delay, n, samples)
-    if "q" in table:
-        srt = _sorted_prefix(path, k2c)
-        return jax.vmap(lambda q: d0 + _kth(srt, q[1]))(table["q"])
-    srt, perm = _topk_ascending(path, k2c)
-    return jax.vmap(lambda m: d0 + _sat_time(srt, perm, m["p2c_w"],
-                                             m["p2c_t"]))(
-        {k: table[k] for k in MASK_KEYS})
+    with jax.named_scope("repro.decide"):
+        if "q" in table:
+            srt = _sorted_prefix(path, k2c)
+            return jax.vmap(lambda q: d0 + _kth(srt, q[1]))(table["q"])
+        srt, perm = _topk_ascending(path, k2c)
+        return jax.vmap(lambda m: d0 + _sat_time(srt, perm, m["p2c_w"],
+                                                 m["p2c_t"]))(
+            {k: table[k] for k in MASK_KEYS})
 
 
 @functools.partial(jax.jit, static_argnames=("n", "samples"))

@@ -182,6 +182,7 @@ class StreamSummary:
         return self.hist.shape[-1]
 
     # -- online updates ----------------------------------------------------
+    @jax.named_scope("repro.sketch")
     def update(self, out: Dict[str, jax.Array],
                valid: jax.Array) -> "StreamSummary":
         """Absorb one chunk: ``out`` is an (M, C) outcome dict, ``valid`` a
@@ -206,6 +207,7 @@ class StreamSummary:
             cnt=add_cnt.astype(jnp.float32), lat_sum=add_sum,
             lat_max=add_max, hist=add_hist)
 
+    @jax.named_scope("repro.sketch")
     def _absorb(self, *, n_trials, n_fast, n_recovery, n_undecided, cnt,
                 lat_sum, lat_max, hist) -> "StreamSummary":
         """Merge per-chunk aggregates (the fused kernel's output shape)."""
@@ -239,6 +241,7 @@ class StreamSummary:
             lat_sum=other.mean_ms * other.n_decided.astype(jnp.float32),
             lat_max=other.max_ms, hist=other.hist)
 
+    @jax.named_scope("repro.merge")
     def axis_merge(self, axis_name: str) -> "StreamSummary":
         """Cross-device merge inside ``shard_map``: psum the counts and the
         sketch, pmax the max, count-weighted psum for the mean."""
@@ -349,6 +352,7 @@ def _dummy_layout() -> tuple:
     return (jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32))
 
 
+@jax.named_scope("repro.sketch")
 def _cols_card_update(state: StreamSummary, cols: jax.Array,
                       col_of_m: jax.Array, valid: jax.Array, *,
                       fast: bool) -> StreamSummary:
@@ -433,72 +437,81 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
     win = engine._win_sorted(draws)                      # (C, k2f) ascending
     V = k2f + 1                                          # fcap slots 0..k2f
 
-    nfin = (win < UNDECIDED_MS).sum(axis=-1).astype(jnp.int32)
-    fcap = jnp.minimum(draws["max_cnt"], nfin)           # (C,) in [0, k2f]
-    vkey = jnp.where(valid, fcap, V)                     # V = padding slot
+    with jax.named_scope("repro.decide"):
+        nfin = (win < UNDECIDED_MS).sum(axis=-1).astype(jnp.int32)
+        fcap = jnp.minimum(draws["max_cnt"], nfin)       # (C,) in [0, k2f]
+        vkey = jnp.where(valid, fcap, V)                 # V = padding slot
 
     # ---- fast side: winner-2b prefix columns ------------------------------
-    bwin = bucket_index(win, prec)                       # (C, k2f)
-    fkey = (jnp.arange(k2f, dtype=jnp.int32)[None, :] * (V + 1)
-            + vkey[:, None]) * B + bwin
-    FH = jnp.zeros((k2f * (V + 1) * B,), jnp.int32).at[fkey.ravel()].add(1)
-    FH = FH.reshape(k2f, V + 1, B)[:, :V]                # drop padding slot
-    SFH = jnp.flip(jnp.cumsum(jnp.flip(FH, 1), axis=1), 1)   # suffix over v
-    hist_fast = SFH[q2f - 1, q2f]                        # (M, B)
+    with jax.named_scope("repro.sketch"):
+        bwin = bucket_index(win, prec)                   # (C, k2f)
+        fkey = (jnp.arange(k2f, dtype=jnp.int32)[None, :] * (V + 1)
+                + vkey[:, None]) * B + bwin
+        FH = jnp.zeros((k2f * (V + 1) * B,),
+                       jnp.int32).at[fkey.ravel()].add(1)
+        FH = FH.reshape(k2f, V + 1, B)[:, :V]            # drop padding slot
+        # suffix sums over v
+        SFH = jnp.flip(jnp.cumsum(jnp.flip(FH, 1), axis=1), 1)
+        hist_fast = SFH[q2f - 1, q2f]                    # (M, B)
 
-    oh = (vkey[:, None] == jnp.arange(V, dtype=jnp.int32)[None, :]
-          ).astype(jnp.float32)                          # (C, V) valid only
-    # HIGHEST: on the TPU the default f32 matmul rounds its operands to
-    # bf16, which would put a ~1e-3 relative error on the latency sums.
-    hi = jax.lax.Precision.HIGHEST
-    Fsum = jnp.einsum("cj,cv->jv", win, oh, precision=hi)    # (k2f, V)
-    SFsum = jnp.flip(jnp.cumsum(jnp.flip(Fsum, 1), axis=1), 1)
-    sum_fast = SFsum[q2f - 1, q2f]                       # (M,)
+        oh = (vkey[:, None] == jnp.arange(V, dtype=jnp.int32)[None, :]
+              ).astype(jnp.float32)                      # (C, V) valid only
+        # HIGHEST: on the TPU the default f32 matmul rounds its operands to
+        # bf16, which would put a ~1e-3 relative error on the latency sums.
+        hi = jax.lax.Precision.HIGHEST
+        Fsum = jnp.einsum("cj,cv->jv", win, oh, precision=hi)  # (k2f, V)
+        SFsum = jnp.flip(jnp.cumsum(jnp.flip(Fsum, 1), axis=1), 1)
+        sum_fast = SFsum[q2f - 1, q2f]                   # (M,)
 
-    # per-slot column maxima: static loop over the <= n + 1 slots.
-    Fmax = jnp.stack([jnp.where((vkey == v)[:, None], win, -jnp.inf).max(0)
-                      for v in range(V)], axis=1)        # (k2f, V)
-    SFmax = jnp.flip(jax.lax.cummax(jnp.flip(Fmax, 1), axis=1), 1)
-    max_fast = SFmax[q2f - 1, q2f]                       # (M,)
+        # per-slot column maxima: static loop over the <= n + 1 slots.
+        Fmax = jnp.stack(
+            [jnp.where((vkey == v)[:, None], win, -jnp.inf).max(0)
+             for v in range(V)], axis=1)                 # (k2f, V)
+        SFmax = jnp.flip(jax.lax.cummax(jnp.flip(Fmax, 1), axis=1), 1)
+        max_fast = SFmax[q2f - 1, q2f]                   # (M,)
 
-    cnt_v = jnp.zeros((V + 1,), jnp.int32).at[vkey].add(1)[:V]
-    scnt = jnp.flip(jnp.cumsum(jnp.flip(cnt_v, 0)), 0)   # suffix counts
-    n_fast = scnt[q2f]                                   # (M,)
+        cnt_v = jnp.zeros((V + 1,), jnp.int32).at[vkey].add(1)[:V]
+        scnt = jnp.flip(jnp.cumsum(jnp.flip(cnt_v, 0)), 0)  # suffix counts
+        n_fast = scnt[q2f]                               # (M,)
 
     # ---- recovery side: (q1, q2c) pair columns ----------------------------
-    t_rec = (jnp.take(draws["sorted_arrive"], pairs[:, 0] - 1, axis=1)
-             + jnp.take(draws["sorted_classic"], pairs[:, 1] - 1, axis=1))
-    dec = t_rec < UNDECIDED_MS                           # (C, P)
-    brec = jnp.where(dec, bucket_index(t_rec, prec), B)  # bucket B: undecided
-    rkey = (jnp.arange(P_, dtype=jnp.int32)[None, :] * (V + 1)
-            + vkey[:, None]) * (B + 1) + brec
-    RH = jnp.zeros((P_ * (V + 1) * (B + 1),),
-                   jnp.int32).at[rkey.ravel()].add(1)
-    RH = RH.reshape(P_, V + 1, B + 1)[:, :V]
-    CRH = jnp.cumsum(RH, axis=1)                         # prefix over v
-    rec_rows = CRH[pair_of_m, q2f - 1]                   # (M, B + 1)
-    hist_rec = rec_rows[:, :B]
-    n_und = rec_rows[:, B]
-    n_rec = hist_rec.sum(axis=-1)
+    with jax.named_scope("repro.decide"):
+        t_rec = (jnp.take(draws["sorted_arrive"], pairs[:, 0] - 1, axis=1)
+                 + jnp.take(draws["sorted_classic"], pairs[:, 1] - 1,
+                            axis=1))
+        dec = t_rec < UNDECIDED_MS                       # (C, P)
+    with jax.named_scope("repro.sketch"):
+        # bucket B: undecided
+        brec = jnp.where(dec, bucket_index(t_rec, prec), B)
+        rkey = (jnp.arange(P_, dtype=jnp.int32)[None, :] * (V + 1)
+                + vkey[:, None]) * (B + 1) + brec
+        RH = jnp.zeros((P_ * (V + 1) * (B + 1),),
+                       jnp.int32).at[rkey.ravel()].add(1)
+        RH = RH.reshape(P_, V + 1, B + 1)[:, :V]
+        CRH = jnp.cumsum(RH, axis=1)                     # prefix over v
+        rec_rows = CRH[pair_of_m, q2f - 1]               # (M, B + 1)
+        hist_rec = rec_rows[:, :B]
+        n_und = rec_rows[:, B]
+        n_rec = hist_rec.sum(axis=-1)
 
-    Rsum = jnp.einsum("cp,cv->pv", jnp.where(dec, t_rec, 0.0), oh,
-                      precision=hi)
-    CRsum = jnp.cumsum(Rsum, axis=1)
-    sum_rec = CRsum[pair_of_m, q2f - 1]
+        Rsum = jnp.einsum("cp,cv->pv", jnp.where(dec, t_rec, 0.0), oh,
+                          precision=hi)
+        CRsum = jnp.cumsum(Rsum, axis=1)
+        sum_rec = CRsum[pair_of_m, q2f - 1]
 
-    Rmax = jnp.stack(
-        [jnp.where((vkey == v)[:, None] & dec, t_rec, -jnp.inf).max(0)
-         for v in range(V)], axis=1)                     # (P, V)
-    CRmax = jax.lax.cummax(Rmax, axis=1)
-    max_rec = CRmax[pair_of_m, q2f - 1]
+        Rmax = jnp.stack(
+            [jnp.where((vkey == v)[:, None] & dec, t_rec, -jnp.inf).max(0)
+             for v in range(V)], axis=1)                 # (P, V)
+        CRmax = jax.lax.cummax(Rmax, axis=1)
+        max_rec = CRmax[pair_of_m, q2f - 1]
 
-    n_valid = jnp.broadcast_to(valid.sum().astype(jnp.int32), q2f.shape)
-    return state._absorb(
-        n_trials=n_valid, n_fast=n_fast, n_recovery=n_rec,
-        n_undecided=n_und, cnt=(n_fast + n_rec).astype(jnp.float32),
-        lat_sum=sum_fast + sum_rec,
-        lat_max=jnp.maximum(max_fast, max_rec),
-        hist=hist_fast + hist_rec)
+        n_valid = jnp.broadcast_to(valid.sum().astype(jnp.int32), q2f.shape)
+        return state._absorb(
+            n_trials=n_valid, n_fast=n_fast, n_recovery=n_rec,
+            n_undecided=n_und, cnt=(n_fast + n_rec).astype(jnp.float32),
+            lat_sum=sum_fast + sum_rec,
+            lat_max=jnp.maximum(max_fast, max_rec),
+            hist=hist_fast + hist_rec)
 
 
 def _race_fused_update(state: StreamSummary, key, table, offsets, delay,
@@ -527,18 +540,22 @@ def _race_fused_update(state: StreamSummary, key, table, offsets, delay,
     else:
         rec_w, rec_t = table["p2c_w"], table["p2c_t"]
     from repro.kernels.quorum_tally import ops as qt_ops
-    hist, stats = qt_ops.stream_tally_decide_hist(
-        raw["votes"], raw["val_arr"], raw["arrive"], raw["classic"],
-        table["p1_w"], table["p1_t"], rec_w, rec_t,
-        table["p2f_w"], table["p2f_t"], valid, n_values=k_proposers,
-        k_sat=k_sat, precision=state.precision, bins=state.bins,
-        undecided_ms=float(UNDECIDED_MS))
-    return state._absorb(
-        n_trials=stats["n_fast"] + stats["n_recovery"] + stats["n_undecided"],
-        n_fast=stats["n_fast"], n_recovery=stats["n_recovery"],
-        n_undecided=stats["n_undecided"],
-        cnt=(stats["n_fast"] + stats["n_recovery"]).astype(jnp.float32),
-        lat_sum=stats["sum_ms"], lat_max=stats["max_ms"], hist=hist)
+    # one kernel tallies, decides and bins: its time counts as decide
+    with jax.named_scope("repro.decide"):
+        hist, stats = qt_ops.stream_tally_decide_hist(
+            raw["votes"], raw["val_arr"], raw["arrive"], raw["classic"],
+            table["p1_w"], table["p1_t"], rec_w, rec_t,
+            table["p2f_w"], table["p2f_t"], valid, n_values=k_proposers,
+            k_sat=k_sat, precision=state.precision, bins=state.bins,
+            undecided_ms=float(UNDECIDED_MS))
+    with jax.named_scope("repro.sketch"):
+        return state._absorb(
+            n_trials=(stats["n_fast"] + stats["n_recovery"]
+                      + stats["n_undecided"]),
+            n_fast=stats["n_fast"], n_recovery=stats["n_recovery"],
+            n_undecided=stats["n_undecided"],
+            cnt=(stats["n_fast"] + stats["n_recovery"]).astype(jnp.float32),
+            lat_sum=stats["sum_ms"], lat_max=stats["max_ms"], hist=hist)
 
 
 # ---------------------------------------------------------------------------
@@ -583,28 +600,32 @@ def _regime_device_stream(key, table, offsets, delay, trials, regimes, *,
     m = table["p1_w"].shape[0]
     r = regimes.n_regimes
     ep = regimes.epoch_trials
-    zs = regimes.sequence(
-        jax.random.fold_in(key, jnp.int32(REGIME_FOLD_DOMAIN)), n_epochs)
+    with jax.named_scope("repro.sample"):
+        zs = regimes.sequence(
+            jax.random.fold_in(key, jnp.int32(REGIME_FOLD_DOMAIN)), n_epochs)
 
     def body(carry, i):
         occ, states = carry
-        k = jax.random.fold_in(key, i)
-        tidx = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        valid = tidx < trials
-        rid = zs[jnp.clip(tidx // ep, 0, n_epochs - 1)]
+        with jax.named_scope("repro.sample"):
+            k = jax.random.fold_in(key, i)
+            tidx = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+            valid = tidx < trials
+            rid = zs[jnp.clip(tidx // ep, 0, n_epochs - 1)]
         out = _chunk_outcomes(path, k, table, offsets,
                               regimes.mixed_delay(rid), n=n,
                               k_proposers=k_proposers, chunk=chunk,
                               use_kernel=use_kernel, k_sat=k_sat,
                               recovery=recovery)
-        sel = [valid & (rid == j) for j in range(r)]
-        states = tuple(states[j].update(out, sel[j]) for j in range(r))
-        occ = occ + jnp.stack([s.sum() for s in sel]).astype(jnp.int32)
+        with jax.named_scope("repro.sketch"):
+            sel = [valid & (rid == j) for j in range(r)]
+            states = tuple(states[j].update(out, sel[j]) for j in range(r))
+            occ = occ + jnp.stack([s.sum() for s in sel]).astype(jnp.int32)
         return (occ, states), None
 
-    carry0 = psharding.vary_like(
-        (jnp.zeros((r,), jnp.int32),
-         tuple(StreamSummary.zeros(m, precision) for _ in range(r))), key)
+    with jax.named_scope("repro.sketch"):
+        carry0 = psharding.vary_like(
+            (jnp.zeros((r,), jnp.int32),
+             tuple(StreamSummary.zeros(m, precision) for _ in range(r))), key)
     (occ, states), _ = jax.lax.scan(body, carry0,
                                     jnp.arange(n_chunks, dtype=jnp.int32))
     return RegimeStreamSummary(
@@ -645,9 +666,10 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
                 n_chunks=n_chunks, n_epochs=n_epochs, precision=precision,
                 use_kernel=use_kernel, k_sat=k_sat, recovery=recovery)
         def body(state, i):
-            k = jax.random.fold_in(key, i)
-            valid = jnp.arange(chunk, dtype=jnp.int32) \
-                < jnp.clip(trials - i * chunk, 0, chunk)
+            with jax.named_scope("repro.sample"):
+                k = jax.random.fold_in(key, i)
+                valid = jnp.arange(chunk, dtype=jnp.int32) \
+                    < jnp.clip(trials - i * chunk, 0, chunk)
             if fused:
                 state = _race_fused_update(state, k, table, offsets, delay,
                                            valid, n=n,
@@ -668,7 +690,9 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
                                           valid, fast=True)
             elif card:                     # classic_path
                 d0, pathv = engine._classic_path_draws(k, delay, n, chunk)
-                cols = d0[:, None] + engine._sorted_prefix(pathv, k_sat[1])
+                with jax.named_scope("repro.decide"):
+                    cols = d0[:, None] + engine._sorted_prefix(pathv,
+                                                               k_sat[1])
                 state = _cols_card_update(state, cols, table["q"][:, 1] - 1,
                                           valid, fast=False)
             else:
@@ -678,7 +702,9 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
                                       recovery=recovery)
                 state = state.update(out, valid)
             return state, None
-        state0 = psharding.vary_like(StreamSummary.zeros(m, precision), key)
+        with jax.named_scope("repro.sketch"):
+            state0 = psharding.vary_like(StreamSummary.zeros(m, precision),
+                                         key)
         state, _ = jax.lax.scan(body, state0,
                                 jnp.arange(n_chunks, dtype=jnp.int32))
         return state
@@ -695,11 +721,13 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
         # so any process layout of the same global device count runs the
         # same per-device programs and the integer-exact axis_merge makes
         # the merged summary layout-invariant bit-for-bit.
-        d = jax.lax.axis_index(psharding.TRIAL_AXIS)
-        t_d = trials // ndev + jnp.where(d < trials % ndev, 1, 0)
-        # Second fold-in level = device key domain disjoint from chunk keys.
-        k_d = jax.random.fold_in(
-            jax.random.fold_in(key, jnp.int32(DEVICE_FOLD_DOMAIN)), d)
+        with jax.named_scope("repro.sample"):
+            d = jax.lax.axis_index(psharding.TRIAL_AXIS)
+            t_d = trials // ndev + jnp.where(d < trials % ndev, 1, 0)
+            # Second fold-in level = device key domain disjoint from chunk
+            # keys.
+            k_d = jax.random.fold_in(
+                jax.random.fold_in(key, jnp.int32(DEVICE_FOLD_DOMAIN)), d)
         # trials < ndev leaves trailing devices with t_d == 0: they would
         # still scan n_chunks all-invalid chunks.  Short-circuit them to
         # the zeros identity (exact under merge: counts/hist 0, max -inf)
@@ -718,10 +746,11 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
             # per-regime slices merge exactly like plain summaries (their
             # leaves just carry a leading R axis); occupancy is an exact
             # integer psum.
+            with jax.named_scope("repro.merge"):
+                occupancy = jax.lax.psum(state.occupancy,
+                                         psharding.TRIAL_AXIS)
             return replace(
-                state,
-                occupancy=jax.lax.psum(state.occupancy,
-                                       psharding.TRIAL_AXIS),
+                state, occupancy=occupancy,
                 by_regime=state.by_regime.axis_merge(psharding.TRIAL_AXIS))
         return state.axis_merge(psharding.TRIAL_AXIS)
 
@@ -782,54 +811,59 @@ def _resolve_k_sat(table, k_max, n: int):
 def _stream_entry(path: str, key, table, delay, offsets, *, n, k_proposers,
                   trials, chunk, precision, use_kernel, shard, k_max="auto",
                   regimes=None, recovery="coordinated") -> StreamSummary:
-    engine._check_mask_table(table, n)
-    engine._check_recovery(recovery)
-    if trials < 1:
-        raise ValueError(f"trials must be >= 1, got {trials}")
-    if chunk < 1:
-        raise ValueError(f"chunk must be >= 1, got {chunk}")
-    sketch_bins(precision)             # validates precision
-    if regimes is not None:
-        if isinstance(regimes, dict):
-            regimes = MarkovRegimes.from_config(regimes, n)
-        regimes = regimes.validate().bound(
-            delay if delay is not None else default_delay())
-    mesh = _resolve_mesh(shard)
-    if mesh is None and trials <= chunk and regimes is None:
-        # The materializing path IS the T <= chunk special case: same
-        # compile as direct engine calls, bit-identical draws, reduced.
-        if path == "race":
-            out = engine.race(key, table, offsets, delay, n=n,
-                              k_proposers=k_proposers, samples=trials,
-                              use_kernel=use_kernel, recovery=recovery)
-        elif path == "fast_path":
-            out = _lat_only_outcomes(
-                engine.fast_path(key, table, delay, n=n, samples=trials),
-                fast=True)
-        else:
-            out = _lat_only_outcomes(
-                engine.classic_path(key, table, delay, n=n, samples=trials),
-                fast=False)
-        return StreamSummary.from_outcomes(out, precision)
-    k_sat = _resolve_k_sat(table, k_max, n)
-    layout = (_card_layout(table, recovery)
-              if "q" in table and k_sat is not None else _dummy_layout())
-    ndev = 1 if mesh is None else mesh.shape[psharding.TRIAL_AXIS]
-    per_device = -(-trials // ndev)                # ceil: busiest device
-    n_chunks = -(-per_device // chunk)
-    # Regime epochs cover the scan's static per-device trial capacity, so
-    # n_epochs is a pure function of the jit geometry (trials stays traced).
-    n_epochs = (1 if regimes is None
-                else -(-(n_chunks * chunk) // regimes.epoch_trials))
-    if delay is None:
-        delay = default_delay()
-    offsets = (jnp.zeros((1,), jnp.float32) if offsets is None
-               else jnp.asarray(offsets, jnp.float32))
-    return _stream(key, table, layout, offsets, delay, jnp.int32(trials),
-                   regimes, path=path, n=n, k_proposers=k_proposers,
-                   chunk=chunk, n_chunks=n_chunks, n_epochs=n_epochs,
-                   precision=precision, use_kernel=use_kernel, mesh=mesh,
-                   k_sat=k_sat, recovery=recovery)
+    """One stream pass under the host span ``repro.stream.<path>``: checks,
+    sort-free depths, pair layout and the asynchronous dispatch of
+    ``_stream``."""
+    with jax.profiler.TraceAnnotation(f"repro.stream.{path}"):
+        engine._check_mask_table(table, n)
+        engine._check_recovery(recovery)
+        if trials < 1:
+            raise ValueError(f"trials must be >= 1, got {trials}")
+        if chunk < 1:
+            raise ValueError(f"chunk must be >= 1, got {chunk}")
+        sketch_bins(precision)             # validates precision
+        if regimes is not None:
+            if isinstance(regimes, dict):
+                regimes = MarkovRegimes.from_config(regimes, n)
+            regimes = regimes.validate().bound(
+                delay if delay is not None else default_delay())
+        mesh = _resolve_mesh(shard)
+        if mesh is None and trials <= chunk and regimes is None:
+            # The materializing path IS the T <= chunk special case: same
+            # compile as direct engine calls, bit-identical draws, reduced.
+            if path == "race":
+                out = engine.race(key, table, offsets, delay, n=n,
+                                  k_proposers=k_proposers, samples=trials,
+                                  use_kernel=use_kernel, recovery=recovery)
+            elif path == "fast_path":
+                out = _lat_only_outcomes(
+                    engine.fast_path(key, table, delay, n=n, samples=trials),
+                    fast=True)
+            else:
+                out = _lat_only_outcomes(
+                    engine.classic_path(key, table, delay, n=n,
+                                        samples=trials), fast=False)
+            return StreamSummary.from_outcomes(out, precision)
+        k_sat = _resolve_k_sat(table, k_max, n)
+        layout = (_card_layout(table, recovery)
+                  if "q" in table and k_sat is not None else _dummy_layout())
+        ndev = 1 if mesh is None else mesh.shape[psharding.TRIAL_AXIS]
+        per_device = -(-trials // ndev)                # ceil: busiest device
+        n_chunks = -(-per_device // chunk)
+        # Regime epochs cover the scan's static per-device trial capacity,
+        # so n_epochs is a pure function of the jit geometry (trials stays
+        # traced).
+        n_epochs = (1 if regimes is None
+                    else -(-(n_chunks * chunk) // regimes.epoch_trials))
+        if delay is None:
+            delay = default_delay()
+        offsets = (jnp.zeros((1,), jnp.float32) if offsets is None
+                   else jnp.asarray(offsets, jnp.float32))
+        return _stream(key, table, layout, offsets, delay, jnp.int32(trials),
+                       regimes, path=path, n=n, k_proposers=k_proposers,
+                       chunk=chunk, n_chunks=n_chunks, n_epochs=n_epochs,
+                       precision=precision, use_kernel=use_kernel, mesh=mesh,
+                       k_sat=k_sat, recovery=recovery)
 
 
 def race_stream(key, table, offsets, delay=None, *, n: int, k_proposers: int,
