@@ -185,21 +185,6 @@ class QuorumMasks:
         """(G1, G2c, G2f) quorum-row counts."""
         return (self.p1_w.shape[0], self.p2c_w.shape[0], self.p2f_w.shape[0])
 
-    def pad_groups(self, g1: int, g2c: int, g2f: int) -> "QuorumMasks":
-        """Pad each phase to the given row count with never-satisfied rows."""
-        def pad(w, t, g):
-            G = w.shape[0]
-            if g < G:
-                raise ValueError(f"cannot pad {G} rows down to {g}")
-            return (np.concatenate([w, np.zeros((g - G, self.n), np.float32)]),
-                    np.concatenate([t, np.full(g - G, PAD_THRESHOLD,
-                                               np.float32)]))
-        p1w, p1t = pad(self.p1_w, self.p1_t, g1)
-        p2cw, p2ct = pad(self.p2c_w, self.p2c_t, g2c)
-        p2fw, p2ft = pad(self.p2f_w, self.p2f_t, g2f)
-        return QuorumMasks(self.n, p1w, p1t, p2cw, p2ct, p2fw, p2ft,
-                           self.label)
-
     def embed(self, n: int) -> "QuorumMasks":
         """Re-express over a larger cluster: acceptors >= self.n get zero
         weight everywhere (present but never counted), letting systems of

@@ -8,6 +8,8 @@ lowering**: the membership-mask table built by ``build_mask_table``
 (DESIGN.md §2).  Cardinality specs, grids, weighted voting and hand-built
 explicit systems all become per-phase (M, G, n) float32 weight matrices
 plus (M, G) thresholds — all traced, so same-shape tables reuse a compile.
+The table is assembled on the host in numpy and crosses to the device in
+one transfer, so building it launches no device program.
 
 When *every* system in a table is cardinality-encodable (single all-ones
 row per phase, integral threshold), ``build_mask_table`` additionally
@@ -59,8 +61,9 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.quorum import QuorumMasks, QuorumSpec
+from repro.core.quorum import PAD_THRESHOLD, QuorumMasks, QuorumSpec
 
 from . import latency as lat_mod
 from .latency import LOST_MS, default_delay
@@ -114,6 +117,11 @@ def build_mask_table(systems: Sequence, *,
     ``*_w (M, G, n)`` weight and ``*_t (M, G)`` threshold float32 arrays.
     Tables of the same shape are interchangeable without recompiling.
 
+    The arrays are filled on the host in one pass over the systems and
+    cross to the device in a single ``jax.device_put``: no device program
+    runs.  With no device named the arrays stay uncommitted on the default
+    device, so a ``shard_map`` over a trial mesh may still place them.
+
     When every system is cardinality-encodable (one all-ones row per phase,
     integral threshold) the table also carries ``"q"`` — the (M, 3) int32
     thresholds — and the engine entry points lower to the k-th-order-
@@ -132,18 +140,22 @@ def build_mask_table(systems: Sequence, *,
                 f"({_sys_label(systems[i], m)}) has n={m.n} but system 0 "
                 f"({_sys_label(systems[0], masks[0])}) has n={n}; "
                 f"use QuorumMasks.embed() or rebuild the systems on one n")
-    g1 = max(m.groups[0] for m in masks)
-    g2c = max(m.groups[1] for m in masks)
-    g2f = max(m.groups[2] for m in masks)
-    padded = [m.pad_groups(g1, g2c, g2f) for m in masks]
-    table = {k: jnp.stack([jnp.asarray(getattr(m, k), jnp.float32)
-                           for m in padded])
-             for k in MASK_KEYS}
+    table = {}          # padding rows: zero weight, never-met threshold
+    for p, ph in enumerate(("p1", "p2c", "p2f")):
+        g = max(m.groups[p] for m in masks)
+        table[ph + "_w"] = np.zeros((len(masks), g, n), np.float32)
+        table[ph + "_t"] = np.full((len(masks), g), PAD_THRESHOLD,
+                                   np.float32)
+    for i, m in enumerate(masks):
+        for k in MASK_KEYS:
+            rows = getattr(m, k)
+            table[k][i, :len(rows)] = rows
     if specialize:
         qs = [m.cardinality_q() for m in masks]
         if all(q is not None for q in qs):
-            table["q"] = jnp.array(qs, jnp.int32)
-    return table
+            table["q"] = np.array(qs, np.int32)
+    placed = jax.device_put(table)          # a dict comes back key-sorted
+    return {k: placed[k] for k in table}
 
 
 def _check_mask_table(table, n: int) -> None:
@@ -185,7 +197,6 @@ def saturation_depths(table: Dict[str, jax.Array]) -> Tuple[int, int, int]:
     Host-side and concrete (a table is concrete at stream entry); the
     result is a static compile key for the prefix shapes.
     """
-    import numpy as np
     n = int(table["p1_w"].shape[-1])
 
     def depth(w, t):

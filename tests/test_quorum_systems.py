@@ -5,6 +5,7 @@ decide-bits that match brute-force set semantics.  Per Relaxed Paxos
 (Howard & Mortier 2022), exhaustive checking of small systems against the
 simulator and model checker is what licenses the fast path.
 """
+import glob
 import itertools
 
 import jax
@@ -14,8 +15,11 @@ import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.model_check import explore
-from repro.core.quorum import (ExplicitQuorumSystem, QuorumSpec,
+from repro.core.quorum import (PAD_THRESHOLD, ExplicitQuorumSystem,
+                               QuorumMasks, QuorumSpec,
                                WeightedQuorumSystem, all_valid_specs)
+from repro.frontier.families import (cardinality_family, grid_family,
+                                     weighted_family)
 from repro.kernels.quorum_tally import ref as qt_ref
 from repro.montecarlo import build_mask_table, engine
 
@@ -197,6 +201,97 @@ def test_mask_table_mixed_n_error_names_offender():
     msg = str(exc.value)
     assert "system 1" in msg and "n=9" in msg and "n=11" in msg
     assert "embed" in msg                        # actionable hint
+
+
+def _stacked_reference(systems, specialize=True):
+    """The table as plain per-array stacks of each system's masks, each
+    phase padded to the batch's row count with zero-weight rows that no
+    arrival set can meet."""
+    masks = [s if isinstance(s, QuorumMasks) else s.to_masks()
+             for s in systems]
+    ref = {}
+    for p, ph in enumerate(("p1", "p2c", "p2f")):
+        g = max(m.groups[p] for m in masks)
+        ws, ts = [], []
+        for m in masks:
+            w, t = getattr(m, ph + "_w"), getattr(m, ph + "_t")
+            pad = g - w.shape[0]
+            ws.append(np.concatenate([w, np.zeros((pad, m.n), np.float32)]))
+            ts.append(np.concatenate([t, np.full(pad, PAD_THRESHOLD,
+                                                 np.float32)]))
+        ref[ph + "_w"] = np.stack([np.asarray(w, np.float32) for w in ws])
+        ref[ph + "_t"] = np.stack([np.asarray(t, np.float32) for t in ts])
+    qs = [m.cardinality_q() for m in masks]
+    if specialize and all(q is not None for q in qs):
+        ref["q"] = np.array(qs, np.int32)
+    return ref
+
+
+FFP_SPACE = [m.masks(11) for m in cardinality_family(11)]
+TABLE_CASES = {
+    # (systems, specialize, carries "q")
+    "ffp_space_n11": (FFP_SPACE, True, True),
+    "grid_and_weighted": ([QuorumSpec.paper_headline(11),
+                           grid_family(11)[-1].masks(11),
+                           weighted_family(11)[0].masks(11)], True, False),
+    "embedded_n3": ([QuorumSpec.paper_headline(11),
+                     ExplicitQuorumSystem.grid(1).to_masks().embed(11)],
+                    True, False),
+    "unspecialized": (FFP_SPACE, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(TABLE_CASES))
+def test_mask_table_equals_the_stacked_reference(case):
+    """The host-assembled table is the per-array stack, key for key and
+    bit for bit, as uncommitted strong-typed arrays on the default
+    device (so ``shard_map`` may still place it on a mesh)."""
+    systems, specialize, has_q = TABLE_CASES[case]
+    table = build_mask_table(systems, specialize=specialize)
+    ref = _stacked_reference(systems, specialize)
+    assert list(table) == list(ref)
+    assert ("q" in table) == has_q
+    assert table["p1_w"].shape[0] == len(systems)
+    for k, want in ref.items():
+        got = table[k]
+        assert isinstance(got, jax.Array), k
+        assert (got.shape, got.dtype) == (want.shape, want.dtype), k
+        assert not got.weak_type and not got.committed, k
+        assert got.devices() == {jax.devices()[0]}, k
+        assert np.asarray(got).tobytes() == want.tobytes(), k
+
+
+def test_mask_table_is_one_transfer_and_no_device_program(monkeypatch,
+                                                          tmp_path):
+    """The table is filled on the host and crosses in one ``device_put``:
+    no ``jnp`` stacking, and no executable runs while it is built."""
+    from jax.profiler import ProfileData
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(*args, **kwargs):
+        puts.append(args)
+        return real_put(*args, **kwargs)
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("mask table built with jnp on the device")
+
+    build_mask_table(FFP_SPACE)                   # imports, first use
+    monkeypatch.setattr(engine.jax, "device_put", counting_put)
+    for name in ("stack", "asarray", "array", "concatenate"):
+        monkeypatch.setattr(engine.jnp, name, refuse)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        table = jax.block_until_ready(build_mask_table(FFP_SPACE))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(puts) == 1 and "q" in table
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events]
+    assert "DevicePutWithSharding" in names       # the trace saw the transfer
+    assert not [n for n in names if "Execute" in n]   # no program ran
 
 
 def test_fast_and_classic_path_lowerings_bit_identical():
