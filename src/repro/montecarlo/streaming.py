@@ -395,6 +395,54 @@ def _cols_card_update(state: StreamSummary, cols: jax.Array,
         lat_sum=col_sum[col_of_m], lat_max=col_max[col_of_m], hist=hist)
 
 
+def _slot_hist_scatter(bkey: jax.Array, slot: jax.Array, *, n_buckets: int,
+                       n_slots: int) -> jax.Array:
+    """``_slot_hist`` as one flat-key scatter-add: C * G updates, which a
+    TPU applies one after another.  Entries off the grid go to a padding
+    slot that is cut away."""
+    G = bkey.shape[1]
+    keep = (bkey < n_buckets) & (slot < n_slots)[:, None]
+    s = jnp.where(keep, slot[:, None], n_slots)
+    b = jnp.where(keep, bkey, 0)
+    flat = (jnp.arange(G, dtype=jnp.int32)[None, :] * (n_slots + 1)
+            + s) * n_buckets + b
+    h = jnp.zeros((G * (n_slots + 1) * n_buckets,),
+                  jnp.int32).at[flat.ravel()].add(1)
+    return h.reshape(G, n_slots + 1, n_buckets)[:, :n_slots]
+
+
+def _slot_hist_onehot(bkey: jax.Array, slot: jax.Array, *, n_buckets: int,
+                      n_slots: int) -> jax.Array:
+    """``_slot_hist`` as one MXU contraction over trials of a bucket
+    one-hot (C, G, n_buckets) with a slot one-hot (C, n_slots).  The TPU
+    compiler builds both one-hots inside the contraction's fusion, so
+    neither reaches HBM.  Entries are 0/1 int8 and the MXU accumulates in
+    int32, so the counts are exact."""
+    ob = (bkey[:, :, None] == jnp.arange(n_buckets, dtype=jnp.int32)
+          ).astype(jnp.int8)
+    ov = (slot[:, None] == jnp.arange(n_slots, dtype=jnp.int32)
+          ).astype(jnp.int8)
+    return jnp.einsum("cgb,cv->gvb", ob, ov,
+                      preferred_element_type=jnp.int32)
+
+
+def _slot_hist(bkey: jax.Array, slot: jax.Array, *, n_buckets: int,
+               n_slots: int) -> jax.Array:
+    """Histogram ``(G, n_slots, n_buckets)`` int32 of C trials: trial c
+    counts once in ``[g, slot[c], bkey[c, g]]`` for every group g.  Keys
+    are non-negative; an entry whose slot is ``n_slots`` or more, or whose
+    bucket is ``n_buckets`` or more, is dropped.
+
+    The compile target picks the lowering: the MXU contraction on the TPU,
+    the scatter elsewhere (the CPU compiler would write the one-hots out).
+    Both give the same integers."""
+    kw = dict(n_buckets=n_buckets, n_slots=n_slots)
+    with jax.named_scope("slot_hist"):
+        return jax.lax.platform_dependent(
+            bkey, slot, tpu=functools.partial(_slot_hist_onehot, **kw),
+            default=functools.partial(_slot_hist_scatter, **kw))
+
+
 def _race_card_update(state: StreamSummary, key, table, layout, offsets,
                       delay, valid, *, n, k_proposers, chunk, use_kernel,
                       k_sat, recovery="coordinated") -> StreamSummary:
@@ -414,10 +462,13 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
       * matching per-slot sums (one-hot matmuls, no scatter) and maxima
         (static loop over the <= n+1 slots).
 
-    Scatter volume drops from M * chunk to (k2f + P) * chunk updates; every
-    integer output (decide bits, histogram, counts, max) is bit-identical
-    to the materialized ``_decide`` + ``state.update`` path — only the f32
-    latency-sum reduction order differs.
+    Both histograms come from ``_slot_hist``, (k2f + P) * chunk counts per
+    chunk instead of M * chunk: on the TPU one-hot contractions over the
+    trial axis (every trial's columns share its fcap slot), elsewhere
+    scatter-adds.  Every integer output (decide bits, histogram, counts,
+    max) is bit-identical to the materialized ``_decide`` +
+    ``state.update`` path — only the f32 latency-sum reduction order
+    differs.
 
     ``recovery`` rides through unchanged: ``layout`` already pairs each
     system with the rule's commit threshold (q2c or q2f) and
@@ -430,7 +481,6 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
                                 use_kernel=use_kernel, k_sat=k_sat,
                                 need_perms=False, recovery=recovery)
     pairs, pair_of_m = layout                            # (P, 2), (M,)
-    P_ = pairs.shape[0]
     q2f = table["q"][:, 2]                               # (M,) traced
     B = state.bins
     prec = state.precision
@@ -445,11 +495,7 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
     # ---- fast side: winner-2b prefix columns ------------------------------
     with jax.named_scope("repro.sketch"):
         bwin = bucket_index(win, prec)                   # (C, k2f)
-        fkey = (jnp.arange(k2f, dtype=jnp.int32)[None, :] * (V + 1)
-                + vkey[:, None]) * B + bwin
-        FH = jnp.zeros((k2f * (V + 1) * B,),
-                       jnp.int32).at[fkey.ravel()].add(1)
-        FH = FH.reshape(k2f, V + 1, B)[:, :V]            # drop padding slot
+        FH = _slot_hist(bwin, vkey, n_buckets=B, n_slots=V)  # (k2f, V, B)
         # suffix sums over v
         SFH = jnp.flip(jnp.cumsum(jnp.flip(FH, 1), axis=1), 1)
         hist_fast = SFH[q2f - 1, q2f]                    # (M, B)
@@ -483,11 +529,7 @@ def _race_card_update(state: StreamSummary, key, table, layout, offsets,
     with jax.named_scope("repro.sketch"):
         # bucket B: undecided
         brec = jnp.where(dec, bucket_index(t_rec, prec), B)
-        rkey = (jnp.arange(P_, dtype=jnp.int32)[None, :] * (V + 1)
-                + vkey[:, None]) * (B + 1) + brec
-        RH = jnp.zeros((P_ * (V + 1) * (B + 1),),
-                       jnp.int32).at[rkey.ravel()].add(1)
-        RH = RH.reshape(P_, V + 1, B + 1)[:, :V]
+        RH = _slot_hist(brec, vkey, n_buckets=B + 1, n_slots=V)  # (P, V, B+1)
         CRH = jnp.cumsum(RH, axis=1)                     # prefix over v
         rec_rows = CRH[pair_of_m, q2f - 1]               # (M, B + 1)
         hist_rec = rec_rows[:, :B]

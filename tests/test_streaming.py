@@ -295,6 +295,91 @@ def test_recovery_rule_streamed_parity_and_invariants():
                     recovery="oracle")
 
 
+SLOT_HIST_CASES = ([(g, c, "spread") for g in (1, 11, 66)
+                    for c in (1, 777, 4_096)]
+                   + [(g, 4_096, "one_bin") for g in (1, 66)])
+
+
+@pytest.mark.parametrize("groups,trials,fill", SLOT_HIST_CASES)
+def test_slot_hist_onehot_lowering_matches_scatter(groups, trials, fill):
+    """The TPU lowering of ``_slot_hist`` (one-hot contraction over trials)
+    gives the scatter lowering's integers bit for bit, called directly on
+    the CPU: buckets at 0, at the last bucket and off the grid, slots
+    including the dropped padding slot, and every trial in one bin (the
+    whole chunk summed exactly)."""
+    nb, ns = 37, 12
+    if fill == "one_bin":
+        bkey = jnp.full((trials, groups), nb - 1, jnp.int32)
+        slot = jnp.zeros((trials,), jnp.int32)
+    else:
+        k1, k2 = jax.random.split(jax.random.PRNGKey(trials * 100 + groups))
+        edges = jnp.array([0, nb - 1, nb, nb + 5], jnp.int32)
+        raw = jax.random.randint(k1, (trials, groups), 0, nb + edges.size)
+        bkey = jnp.where(raw < nb, raw, edges[jnp.clip(raw - nb, 0, 3)])
+        slot = jax.random.randint(k2, (trials,), 0, ns + 2)  # ns: padding
+    kw = dict(n_buckets=nb, n_slots=ns)
+    want = streaming._slot_hist_scatter(bkey, slot, **kw)
+    got = streaming._slot_hist_onehot(bkey, slot, **kw)
+    assert got.shape == want.shape == (groups, ns, nb)
+    assert got.dtype == want.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    kept = ((bkey < nb) & (slot < ns)[:, None]).sum(axis=0)
+    np.testing.assert_array_equal(np.asarray(want.sum(axis=(1, 2))),
+                                  np.asarray(kept))
+    if fill == "one_bin":
+        assert int(want[:, 0, nb - 1].min()) == trials
+
+
+def test_slot_hist_lowers_to_scatter_on_cpu():
+    """On the CPU the dispatch takes the scatter: no contraction is
+    compiled, so no one-hot is written out."""
+    txt = jax.jit(lambda b, s: streaming._slot_hist(
+        b, s, n_buckets=1039, n_slots=12)).lower(
+        jnp.zeros((1_024, 66), jnp.int32),
+        jnp.zeros((1_024,), jnp.int32)).compile().as_text()
+    assert "scatter" in txt
+    assert "dot(" not in txt and "convolution(" not in txt
+
+
+@pytest.fixture
+def slot_hist_onehot(monkeypatch):
+    """Send the stream's histograms through the TPU lowering, for one test:
+    the compiled ``_stream`` programs are dropped before and after so no
+    other test runs a program traced with it."""
+    traced = []
+
+    def onehot(bkey, slot, *, n_buckets, n_slots):
+        traced.append(bkey.shape)
+        return streaming._slot_hist_onehot(bkey, slot, n_buckets=n_buckets,
+                                           n_slots=n_slots)
+    streaming._stream.clear_cache()
+    monkeypatch.setattr(streaming, "_slot_hist", onehot)
+    yield traced
+    monkeypatch.undo()
+    streaming._stream.clear_cache()
+
+
+@pytest.mark.parametrize("recovery", ["coordinated", "uncoordinated"])
+def test_onehot_slot_hist_stream_bit_identical_to_full_sort(
+        slot_hist_onehot, recovery):
+    """The race stream with its histograms counted by the one-hot
+    contraction (the TPU lowering) stays bit-identical to the full-sort
+    reference path, as the scatter lowering is
+    (``test_sortfree_card_streams_bit_identical_to_full_sort``)."""
+    table = build_mask_table([FFP, FP, QuorumSpec.majority_fast(11)])
+    kw = dict(n=11, k_proposers=2, trials=20_000, chunk=1_024, shard=False,
+              recovery=recovery)
+    ref = streaming.race_stream(KEY, table, OFFS, k_max=None, **kw)
+    new = streaming.race_stream(KEY, table, OFFS, k_max="auto", **kw)
+    for f in ("n_trials", "n_fast", "n_recovery", "n_undecided", "hist",
+              "max_ms"):
+        np.testing.assert_array_equal(np.asarray(getattr(new, f)),
+                                      np.asarray(getattr(ref, f)), f)
+    assert np.allclose(np.asarray(new.mean_ms), np.asarray(ref.mean_ms),
+                       rtol=1e-5, equal_nan=True)
+    assert len(slot_hist_onehot) == 2       # FH and RH both went through it
+
+
 def test_recovery_rule_fused_kernel_agrees():
     """The fused Pallas lowering under the uncoordinated rule (recovery
     saturation fed the p2f masks) matches the jnp scatter path."""

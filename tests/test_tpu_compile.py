@@ -1,12 +1,14 @@
-"""Compile the quorum_tally Pallas kernels for a described TPU v5e.
+"""Compile the quorum_tally Pallas kernels, and the streamed race pass,
+for a described TPU v5e.
 
 Interpret-mode parity (tests/test_kernels.py) cannot see what the chip's
 compiler refuses: block shapes off the (8, 128) tiling, fast-memory
 limits, reductions Mosaic cannot lower.  These tests compile each kernel
 ahead of time, ``interpret=False``, for one chip of a described
 ``v5e:2x2`` topology at the widths users run: the n=11 sweep chunk and the
-n=12 mixed-family frontier chunk.  Nothing runs; a passing compile is not a
-chip run.
+n=12 mixed-family frontier chunk.  The race pass's ``_stream`` program is
+compiled the same way, to see which lowering the TPU takes for its sketch
+histograms.  Nothing runs; a passing compile is not a chip run.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -18,8 +20,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.quorum import all_valid_specs
 from repro.kernels.quorum_tally import kernel
 from repro.montecarlo import engine, streaming
+from repro.montecarlo.latency import default_delay
 
 f32, i32 = jnp.float32, jnp.int32
 
@@ -84,3 +88,46 @@ def test_stream_megakernel_compiles_for_v5e(one_chip, shape):
                          ((S, n), f32), ((S, n), f32), *masks,
                          ((S,), jnp.bool_))
     assert "tpu_custom_call" in txt
+
+
+# Temp bytes of the race program below when both sketch histograms were
+# scatter-adds, and what the one-hot contractions may add to them.
+SCATTER_RACE_TEMP_BYTES = 135_249_920
+TEMP_MARGIN_BYTES = 8 << 20
+
+
+def test_race_stream_histograms_contract_on_the_mxu(one_chip):
+    """The benchmark's race pass (all 271 FFP triples at n=11, 2 proposers,
+    chunk 8,192): on the TPU both slot histograms are one-hot contractions
+    under ``slot_hist``, no scatter writes either flat histogram, and the
+    one-hots never reach HBM (temp bytes as with the scatters)."""
+    n, chunk, trials = 11, 8_192, 10 ** 6
+    precision = streaming.DEFAULT_PRECISION
+    table = engine.build_mask_table(list(all_valid_specs(n)))
+    k_sat = streaming._resolve_k_sat(table, "auto", n)
+    layout = streaming._card_layout(table, "coordinated")
+
+    def spec(x):
+        x = jnp.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree_util.tree_map(spec, (
+        jax.random.PRNGKey(0), table, layout,
+        0.2 * jnp.arange(2, dtype=f32), default_delay(), jnp.int32(trials)))
+    compiled = streaming._stream.lower(
+        *args, None, path="race", n=n, k_proposers=2, chunk=chunk,
+        n_chunks=-(-trials // chunk), n_epochs=1, precision=precision,
+        use_kernel=False, mesh=None, k_sat=k_sat,
+        recovery="coordinated").compile()
+    lines = compiled.as_text().splitlines()
+
+    pairs, k2f = layout[0].shape[0], k_sat[2]
+    V, B = k2f + 1, streaming.sketch_bins(precision)
+    assert (pairs, V, B) == (66, 12, 1038)
+    for flat in (pairs * (V + 1) * (B + 1), k2f * (V + 1) * B):
+        assert not [l for l in lines if f"s32[{flat}]" in l
+                    and "scatter" in l], flat
+    convs = [l for l in lines if "convolution(" in l and "/slot_hist/" in l]
+    assert len(convs) == 2, convs
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= SCATTER_RACE_TEMP_BYTES + TEMP_MARGIN_BYTES, temp
