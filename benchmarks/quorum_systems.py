@@ -145,9 +145,10 @@ def run(quick: bool = False, seed: int = 0):
     # scorer (repro.frontier via api.frontier) — which of the §6 families
     # survive dominance once the tail axis is measurable?
     from repro.api import frontier as api_frontier
+    from repro.montecarlo.streaming import StreamSettings
     trials = 131_072 if quick else 2_000_000
-    fr = api_frontier([m for _, m in named], trials=trials, chunk=16_384,
-                      seed=seed)
+    fr = api_frontier([m for _, m in named], trials=trials,
+                      stream=StreamSettings(chunk=16_384), seed=seed)
     rows.append(("qsys.frontier.n_systems", len(fr.labels)))
     rows.append(("qsys.frontier.n_members", len(fr.frontier_indices)))
     for (name, _), lab in zip(named, fr.labels):

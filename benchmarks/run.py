@@ -190,7 +190,8 @@ def streaming_benches(quick: bool):
     # trial count in one-chunk memory (fixed-size state asserted)
     exp = Experiment(systems=[QuorumSpec.paper_headline(11)],
                      workload=Workload.conflict_free(), trials=t_fast,
-                     chunk=chunk, compute_fault_tolerance=False)
+                     stream=streaming.StreamSettings(chunk=chunk),
+                     compute_fault_tolerance=False)
     t0 = time.perf_counter()
     r = exp.run("montecarlo")
     jax.block_until_ready(r.stream.hist)
@@ -393,8 +394,9 @@ def regimes_benches(quick: bool):
     cfg = os.path.join(os.path.dirname(__file__), "..",
                        "examples", "scenarios", "trace_replay.json")
     trials = 200_000 if quick else 1_000_000
-    exp = dataclasses.replace(Experiment.from_config(cfg), trials=trials,
-                              shard=len(jax.devices()) > 1)
+    exp = Experiment.from_config(cfg)
+    exp = dataclasses.replace(exp, trials=trials, stream=dataclasses.replace(
+        exp.stream, shard=len(jax.devices()) > 1))
 
     t0 = dict(engine.TRACE_COUNTS)
     s0 = time.perf_counter()

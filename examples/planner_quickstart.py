@@ -13,6 +13,7 @@ import time
 
 from repro.api import Experiment, Workload, plan
 from repro.core.quorum import QuorumSpec
+from repro.montecarlo.streaming import StreamSettings
 from repro.planner import Planner, PlannerServer, query_server
 
 # One-call front door: search the family, filter the frontier for the
@@ -42,7 +43,8 @@ print(f"[warm] {r2.recommended} in {time.perf_counter() - t0 :.3f}s "
 # An Experiment asks under ITS workload and engine knobs (crashed
 # acceptors are folded into the scored delay model).
 exp = Experiment(systems=[QuorumSpec.paper_headline(11)],
-                 workload=Workload.race(k=3, delta_ms=0.5), shard=False)
+                 workload=Workload.race(k=3, delta_ms=0.5),
+                 stream=StreamSettings(shard=False))
 r3 = exp.plan(faults={"classic": 2}, trials=100_000)
 print(f"[exp]  3-way race @0.5ms, classic>=2: {r3.recommended}")
 

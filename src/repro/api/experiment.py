@@ -43,6 +43,7 @@ from repro.core.model_check import explore
 from repro.core.quorum import (ExplicitQuorumSystem, QuorumMasks, QuorumSpec,
                                WeightedQuorumSystem)
 from repro.core.simulator import FastPaxosSim, LatencyModel
+from repro.frontier import score as fscore
 from repro.montecarlo import engine, streaming
 from repro.montecarlo.latency import (LossyDelay, ShiftedLognormalDelay,
                                       WanDelay, delay_from_config,
@@ -380,14 +381,13 @@ class Experiment:
     the global device grid — 10^7+ trials in one-chunk memory, with
     ``Results`` exposing the same normalized summary keys (plus
     ``p999_ms``/``p9999_ms``, which only streaming trial counts make
-    meaningful) and ``Results.raw`` None.  ``precision`` is the sketch's
-    guaranteed relative quantile error; ``chunk`` the per-step trial
-    block; ``shard`` toggles the trial-axis ``shard_map`` — ``True`` uses
-    all visible devices (every process's, once
+    meaningful) and ``Results.raw`` None.  ``stream`` is the engine's
+    ``streaming.StreamSettings`` (chunk, sketch precision, trial-axis
+    sharding — ``True`` spans every process's devices once
     ``repro.parallel.distributed.initialize()`` has joined a multi-host
-    grid), or pass an explicit 1-D ``jax.sharding.Mesh`` to pin the
-    layout (honored even with a single device).  When ``trials`` is None
-    the materializing path runs unchanged on ``samples``.
+    grid — the fused kernel and the sort-free depths); the materializing
+    path reads only its ``use_kernel``.  When ``trials`` is None the
+    materializing path runs unchanged on ``samples``.
     """
 
     systems: Tuple
@@ -396,18 +396,10 @@ class Experiment:
     backend: str = "montecarlo"
     samples: int = 20_000
     seed: int = 0
-    use_kernel: bool = False
     max_states: int = 200_000      # modelcheck BFS cap
     compute_fault_tolerance: bool = True   # brute-force crash budgets
     trials: Optional[int] = None   # streaming trial count (montecarlo)
-    precision: float = streaming.DEFAULT_PRECISION
-    chunk: int = streaming.DEFAULT_CHUNK
-    shard: bool = True
-    # Sort-free streamed lowering (DESIGN.md §9): "auto" derives the
-    # per-phase top-k selection depths from the mask table, None keeps the
-    # full-sort reference path, an int / 3-tuple pins the depths.  Integer
-    # outputs (decide bits, counts, histograms) are identical either way.
-    k_max: object = "auto"
+    stream: streaming.StreamSettings = streaming.StreamSettings()
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "systems", tuple(self.systems))
@@ -477,19 +469,21 @@ class Experiment:
              "trials": 1000000, "seed": 0}
 
         ``systems`` entries lower through ``system_from_config``;
-        ``workload`` through ``Workload.from_dict``; every remaining key
-        is an ``Experiment`` field."""
+        ``workload`` through ``Workload.from_dict``; the flat keys
+        ``chunk`` / ``precision`` / ``shard`` / ``use_kernel`` / ``k_max``
+        lift into ``stream``; every remaining key is an ``Experiment``
+        field."""
         cfg = path_or_dict
         if isinstance(cfg, (str, Path)):
             with open(cfg) as f:
                 cfg = json.load(f)
-        cfg = dict(cfg)
+        stream, cfg = streaming.StreamSettings.from_flat(cfg)
         systems = [system_from_config(s) for s in cfg.pop("systems")]
         wl = cfg.pop("workload", None)
         workload = (Workload.from_dict(wl) if isinstance(wl, dict)
                     else wl if wl is not None else Workload())
         cfg["faults"] = tuple(cfg.get("faults", ()))
-        return cls(systems=systems, workload=workload, **cfg)
+        return cls(systems=systems, workload=workload, stream=stream, **cfg)
 
     # -- execution ---------------------------------------------------------
     def run(self, backend: Optional[str] = None) -> Results:
@@ -521,10 +515,7 @@ class Experiment:
         return frontier(self.systems, self.workload, n=self.n,
                         faults=self.faults,
                         trials=trials if trials is not None else self.trials,
-                        chunk=self.chunk, precision=self.precision,
-                        shard=self.shard, seed=self.seed,
-                        use_kernel=self.use_kernel, k_max=self.k_max,
-                        axes=axes)
+                        stream=self.stream, seed=self.seed, axes=axes)
 
     def plan(self, family: str = "cardinality", *,
              faults: Optional[Dict[str, int]] = None,
@@ -555,10 +546,8 @@ class Experiment:
                      faults=faults or {},
                      trials=(trials if trials is not None
                              else self.trials or 1_000_000),
-                     objective=objective, chunk=self.chunk,
-                     precision=self.precision, seed=self.seed,
-                     shard=self.shard, use_kernel=self.use_kernel,
-                     k_max=self.k_max, **query_kw)
+                     objective=objective, stream=self.stream,
+                     seed=self.seed, **query_kw)
         return plan(query, planner=planner)
 
     def _fault_tolerance(self) -> Optional[Tuple[Dict[str, int], ...]]:
@@ -574,17 +563,14 @@ class Experiment:
         scen = self.workload.scenario(self.n, self.faults)
         key = jax.random.PRNGKey(self.seed)
         if self.trials is not None:
-            state = scen.with_spec(
-                trials=self.trials, chunk=self.chunk,
-                precision=self.precision, use_kernel=self.use_kernel,
-                shard=self.shard, k_max=self.k_max).stream(
-                    key, self.lower())
+            state = scen.with_spec(trials=self.trials,
+                                   stream=self.stream).stream(
+                                       key, self.lower())
             return Results(backend="montecarlo", labels=self.labels,
                            summary=state.summary(), stream=state,
                            fault_tolerance=self._fault_tolerance())
         out = scen.with_spec(samples=self.samples,
-                             use_kernel=self.use_kernel).run(
-                                 key, self.lower())
+                             stream=self.stream).run(key, self.lower())
         return Results(backend="montecarlo", labels=self.labels,
                        summary=engine.summarize(out), raw=out,
                        fault_tolerance=self._fault_tolerance())
@@ -716,9 +702,8 @@ def sweep(experiment: Experiment, backends: Sequence[str] = BACKENDS
 def frontier(systems: Sequence, workload: Optional[Workload] = None, *,
              n: Optional[int] = None, faults: Sequence[int] = (),
              trials: Optional[int] = None,
-             chunk: Optional[int] = None, precision: Optional[float] = None,
-             shard: bool = True, seed: int = 0, use_kernel: bool = False,
-             k_max="auto", axes=None):
+             stream: streaming.StreamSettings = fscore.DEFAULT_STREAM,
+             seed: int = 0, axes=None):
     """One-call quorum-space Pareto frontier (``repro.frontier``).
 
     ``systems`` is any mix of ``repro.frontier.families.Member``, quorum
@@ -728,9 +713,10 @@ def frontier(systems: Sequence, workload: Optional[Workload] = None, *,
     omitted workloads score under the standard 2-way race at Δ=0.2 ms.
     ``faults`` crashes the named acceptors for the whole run (every hop
     touching them is lost) — note the crash budgets on the ft axes still
-    describe the *intact* systems.  Returns a ``FrontierResult``
-    (``.table()``, ``.to_dict()``, ``.frontier_labels``)."""
-    from repro.frontier import score as fscore
+    describe the *intact* systems.  ``stream`` is the engine's
+    ``streaming.StreamSettings``.
+    Returns a ``FrontierResult`` (``.table()``, ``.to_dict()``,
+    ``.frontier_labels``)."""
     from repro.montecarlo.latency import CrashedDelay
     from repro.montecarlo.scenarios import _crash_mask
 
@@ -748,12 +734,8 @@ def frontier(systems: Sequence, workload: Optional[Workload] = None, *,
         trials=trials if trials is not None else fscore.DEFAULT_TRIALS,
         k_proposers=wl.k_proposers if racing else 2,
         delta_ms=wl.delta_ms if racing else fscore.DEFAULT_DELTA_MS,
-        delay=delay,
-        chunk=chunk if chunk is not None else fscore.DEFAULT_CHUNK,
-        precision=(precision if precision is not None
-                   else streaming.DEFAULT_PRECISION),
-        shard=shard, seed=seed, use_kernel=use_kernel, k_max=k_max,
-        axes=axes, regimes=wl.regimes_for(n), recovery=wl.recovery)
+        delay=delay, seed=seed, axes=axes, regimes=wl.regimes_for(n),
+        recovery=wl.recovery, **stream.to_flat())
 
 
 # Process-wide planner behind ``plan()``: one warm engine pool + search

@@ -34,7 +34,7 @@ actually resolve (``pareto.quantize``).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,10 +48,10 @@ from .pareto import Axis, FrontierResult, pareto_mask
 
 DEFAULT_TRIALS = 1_000_000
 DEFAULT_DELTA_MS = 0.2
-# Smaller than streaming.DEFAULT_CHUNK: the race path materializes
-# (M, chunk, n) gathers per system inside the scan, and frontier batches
-# run to hundreds of systems.
-DEFAULT_CHUNK = 8_192
+# The chunk is smaller than streaming's default: the race path
+# materializes (M, chunk, n) gathers per system inside the scan, and
+# frontier batches run to hundreds of systems.
+DEFAULT_STREAM = streaming.StreamSettings(chunk=8_192)
 
 AXIS_NAMES = ("fast_p50_ms", "race_p999_ms", "p_recovery", "ft_fast",
               "ft_phase1", "ft_classic")
@@ -102,17 +102,38 @@ def _fault_tolerance(system, masks: QuorumMasks) -> Dict[str, int]:
     return masks.fault_tolerance()
 
 
+class _Lowered(NamedTuple):
+    """A batch lowered once for the engine: its masks, the systems they
+    came from, the cluster size, the labels and the mask table."""
+    masks: List[QuorumMasks]
+    native: List
+    n: int
+    labels: Tuple[str, ...]
+    table: Dict
+
+
+def _lower(systems: Sequence, n: Optional[int]) -> _Lowered:
+    """Encode the masks and build the table, under ``repro.score.table``."""
+    span = jax.profiler.TraceAnnotation
+    with span("repro.score.table"):
+        with span("repro.score.masks"):
+            masks, native, n = _as_masks(systems, n)
+        labels = tuple(m.label or f"system{i}" for i, m in enumerate(masks))
+        table = engine.build_mask_table(masks)
+    return _Lowered(masks, native, n, labels, table)
+
+
 def score_systems(systems: Sequence, *,
                   trials: int = DEFAULT_TRIALS,
                   n: Optional[int] = None,
                   k_proposers: int = 2,
                   delta_ms: float = DEFAULT_DELTA_MS,
                   delay=None,
-                  chunk: int = DEFAULT_CHUNK,
-                  precision: float = streaming.DEFAULT_PRECISION,
-                  shard: bool = True,
-                  use_kernel: bool = False,
-                  k_max="auto",
+                  chunk: int = DEFAULT_STREAM.chunk,
+                  precision: float = DEFAULT_STREAM.precision,
+                  shard: bool = DEFAULT_STREAM.shard,
+                  use_kernel: bool = DEFAULT_STREAM.use_kernel,
+                  k_max=DEFAULT_STREAM.k_max,
                   seed: int = 0,
                   regimes=None,
                   recovery: str = "coordinated",
@@ -126,6 +147,9 @@ def score_systems(systems: Sequence, *,
     one compile per engine path, fixed memory, trial axis sharded over
     local devices when ``shard`` — and the five default axes (or a custom
     ``axes`` tuple matching ``AXIS_NAMES``) feed ``pareto.pareto_mask``.
+    ``chunk`` / ``precision`` / ``shard`` / ``use_kernel`` / ``k_max`` are
+    the fields of ``streaming.StreamSettings`` (defaults:
+    ``DEFAULT_STREAM``).
 
     ``k_max`` selects the sort-free streamed lowering (DESIGN.md §9):
     ``"auto"`` (default) derives the per-phase top-k selection depths from
@@ -151,48 +175,53 @@ def score_systems(systems: Sequence, *,
     on the device; ``repro.score.frontier`` for fault tolerance and the
     Pareto mask.
     """
+    stream = streaming.StreamSettings(chunk=chunk, precision=precision,
+                                      shard=shard, use_kernel=use_kernel,
+                                      k_max=k_max)
+    with jax.profiler.TraceAnnotation("repro.score", systems=len(systems),
+                                      trials=trials, seed=seed):
+        return _score(_lower(systems, n), trials=trials,
+                      k_proposers=k_proposers, delta_ms=delta_ms,
+                      delay=delay, stream=stream, seed=seed,
+                      regimes=regimes, recovery=recovery, axes=axes)
+
+
+def _score(low: _Lowered, *, trials: int, k_proposers: int,
+           delta_ms: float, delay, stream: streaming.StreamSettings,
+           seed: int, regimes, recovery: str,
+           axes: Optional[Sequence[Axis]]) -> FrontierResult:
+    """``score_systems`` on a lowered batch: the two stream passes, the
+    readback and the frontier."""
     span = jax.profiler.TraceAnnotation
-    with span("repro.score", systems=len(systems), trials=trials, seed=seed):
-        with span("repro.score.table"):
-            with span("repro.score.masks"):
-                masks, native, n = _as_masks(systems, n)
-            labels = tuple(m.label or f"system{i}"
-                           for i, m in enumerate(masks))
-            table = engine.build_mask_table(masks)
-        axes = tuple(axes) if axes is not None else default_axes(precision,
-                                                                 trials)
+    axes = (tuple(axes) if axes is not None
+            else default_axes(stream.precision, trials))
+    key = jax.random.PRNGKey(seed)
+    k_fast, k_race = jax.random.split(key)
+    offsets = delta_ms * jnp.arange(k_proposers, dtype=jnp.float32)
+    kw = dict(stream.to_flat(), regimes=regimes)
+    use_kernel = kw.pop("use_kernel")
+    fast = streaming.fast_path_stream(k_fast, low.table, delay, n=low.n,
+                                      trials=trials, **kw)
+    race = streaming.race_stream(k_race, low.table, offsets, delay, n=low.n,
+                                 k_proposers=k_proposers, trials=trials,
+                                 use_kernel=use_kernel, recovery=recovery,
+                                 **kw)
 
-        key = jax.random.PRNGKey(seed)
-        k_fast, k_race = jax.random.split(key)
-        offsets = delta_ms * jnp.arange(k_proposers, dtype=jnp.float32)
-
-        fast = streaming.fast_path_stream(k_fast, table, delay, n=n,
-                                          trials=trials, chunk=chunk,
-                                          precision=precision, shard=shard,
-                                          k_max=k_max, regimes=regimes)
-        race = streaming.race_stream(k_race, table, offsets, delay, n=n,
-                                     k_proposers=k_proposers, trials=trials,
-                                     chunk=chunk, precision=precision,
-                                     use_kernel=use_kernel, shard=shard,
-                                     k_max=k_max, regimes=regimes,
-                                     recovery=recovery)
-
-        with span("repro.score.readback"):
-            fast_p50 = np.asarray(fast.quantile(0.5), np.float64)
-            race_p999 = np.asarray(race.quantile(0.999), np.float64)
-            p_rec = (np.asarray(race.n_recovery, np.float64)
-                     / np.maximum(np.asarray(race.n_trials, np.float64),
-                                  1.0))
-        with span("repro.score.frontier"):
-            ft = [_fault_tolerance(s, m) for s, m in zip(native, masks)]
-            values = np.stack([
-                fast_p50,
-                race_p999,
-                p_rec,
-                np.array([f["steady_state_fast"] for f in ft], np.float64),
-                np.array([f["phase1"] for f in ft], np.float64),
-                np.array([f["phase2_classic"] for f in ft], np.float64),
-            ], axis=1)
-            return FrontierResult(labels=labels, axes=axes, values=values,
-                                  mask=pareto_mask(values, axes),
-                                  streams={"fast": fast, "race": race})
+    with span("repro.score.readback"):
+        fast_p50 = np.asarray(fast.quantile(0.5), np.float64)
+        race_p999 = np.asarray(race.quantile(0.999), np.float64)
+        p_rec = (np.asarray(race.n_recovery, np.float64)
+                 / np.maximum(np.asarray(race.n_trials, np.float64), 1.0))
+    with span("repro.score.frontier"):
+        ft = [_fault_tolerance(s, m) for s, m in zip(low.native, low.masks)]
+        values = np.stack([
+            fast_p50,
+            race_p999,
+            p_rec,
+            np.array([f["steady_state_fast"] for f in ft], np.float64),
+            np.array([f["phase1"] for f in ft], np.float64),
+            np.array([f["phase2_classic"] for f in ft], np.float64),
+        ], axis=1)
+        return FrontierResult(labels=low.labels, axes=axes, values=values,
+                              mask=pareto_mask(values, axes),
+                              streams={"fast": fast, "race": race})

@@ -34,9 +34,10 @@ import jax.numpy as jnp
 from repro.core.quorum import (ExplicitQuorumSystem, QuorumMasks,
                                WeightedQuorumSystem)
 
-from . import engine
+from . import engine, streaming
 from .latency import (CrashedDelay, LossyDelay, ShiftedLognormalDelay,
                       WanDelay, default_delay)
+from .streaming import StreamSettings
 
 
 @dataclass(frozen=True)
@@ -48,8 +49,9 @@ class RunSpec:
     ``scenario.with_spec(trials=10**7, faults=(0, 3)).stream(key, table)``.
 
     ``samples`` sizes materializing runs (``run``/``summary``), ``trials``
-    streamed ones; ``chunk``/``precision`` default to the streaming
-    module's defaults when None.  ``faults`` crashes those acceptor ids
+    streamed ones; ``stream`` holds the engine's ``StreamSettings`` (the
+    materializing runs read only its ``use_kernel``).  ``faults`` crashes
+    those acceptor ids
     for the run (``CrashedDelay``); ``regimes`` (a
     ``regimes.MarkovRegimes`` or its config dict) Markov-modulates a
     streamed run through failure epochs (DESIGN.md §12); ``recovery``
@@ -58,11 +60,7 @@ class RunSpec:
 
     samples: int = 20000
     trials: int = 1_000_000
-    chunk: Optional[int] = None
-    precision: Optional[float] = None
-    use_kernel: bool = False
-    shard: bool = True
-    k_max: object = "auto"
+    stream: StreamSettings = StreamSettings()
     faults: Tuple[int, ...] = ()
     regimes: Optional[object] = None
     recovery: str = "coordinated"
@@ -136,7 +134,7 @@ class Scenario:
         n_conf = max(1, int(round(samples * self.conflict_frac)))
         out = engine.race(k_race, table, self.offsets_ms, scen.delay,
                           n=self.n, k_proposers=self.k_proposers,
-                          samples=n_conf, use_kernel=spec.use_kernel,
+                          samples=n_conf, use_kernel=spec.stream.use_kernel,
                           recovery=spec.recovery)
         n_free = samples - n_conf
         if n_free > 0:
@@ -161,12 +159,12 @@ class Scenario:
         """Streamed evaluation: ``spec.trials`` instances reduced
         chunk-by-chunk into a fixed-size ``streaming.StreamSummary`` (device
         memory is one chunk regardless of the trial count; the trial axis
-        shards over local devices when ``spec.shard``).  A mixed workload
+        shards over local devices when ``spec.stream.shard``).  A mixed workload
         streams its racing and conflict-free fractions separately and
         *merges* the two summaries — sketch merge is exact, so the blend
         matches a single mixed stream.
 
-        ``spec.k_max`` selects the sort-free lowering (DESIGN.md §9):
+        ``spec.stream.k_max`` selects the sort-free lowering (DESIGN.md §9):
         "auto" derives per-phase top-k selection depths from the table,
         ``None`` keeps the full-sort reference path; integer outputs are
         identical.  ``spec.regimes`` Markov-modulates the stream through
@@ -177,15 +175,10 @@ class Scenario:
         return self._stream(key, table, self.spec)
 
     def _stream(self, key: jax.Array, table, spec: RunSpec):
-        from . import streaming
         scen = self.with_faults(spec.faults)
         trials = spec.trials
-        kw = dict(
-            chunk=(streaming.DEFAULT_CHUNK if spec.chunk is None
-                   else spec.chunk),
-            precision=(streaming.DEFAULT_PRECISION if spec.precision is None
-                       else spec.precision),
-            shard=spec.shard, k_max=spec.k_max, regimes=spec.regimes)
+        kw = dict(spec.stream.to_flat(), regimes=spec.regimes)
+        use_kernel = kw.pop("use_kernel")
         if self.k_proposers == 1 or self.conflict_frac == 0.0:
             return streaming.fast_path_stream(key, table, scen.delay,
                                               n=self.n, trials=trials, **kw)
@@ -194,8 +187,7 @@ class Scenario:
         state = streaming.race_stream(k_race, table, self.offsets_ms,
                                       scen.delay, n=self.n,
                                       k_proposers=self.k_proposers,
-                                      trials=n_conf,
-                                      use_kernel=spec.use_kernel,
+                                      trials=n_conf, use_kernel=use_kernel,
                                       recovery=spec.recovery, **kw)
         if trials - n_conf > 0:
             free = streaming.fast_path_stream(k_free, table, scen.delay,

@@ -48,7 +48,7 @@ import functools
 import math
 import warnings
 from dataclasses import dataclass, replace
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -802,6 +802,76 @@ def _stream(key, table, layout, offsets, delay, trials, regimes, *, path, n,
         out_specs=P())(key, table, layout, offsets, delay, trials, regimes)
 
 
+# ---------------------------------------------------------------------------
+# StreamSettings: the engine's execution settings, declared once.
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class StreamSettings:
+    """How a stream runs, the one declaration every front door carries.
+
+      chunk       trials per scan step (peak device memory is one chunk)
+      precision   the sketch's guaranteed relative quantile error
+      shard       ``True`` shards the trial axis over every visible device,
+                  ``False`` runs on one, an explicit 1-D ``Mesh`` pins the
+                  layout (honored even with a single device)
+      use_kernel  race chunks on masked tables run the fused megakernel
+      k_max       sort-free depths (DESIGN.md §9): ``"auto"`` derives them
+                  from the table, ``None`` keeps the full-sort reference
+                  path, an int or a ``(k1, k2c, k2f)`` triple pins them
+
+    Construction validates every field; the check of ``k_max`` against a
+    concrete table's saturation depths happens when a stream resolves it.
+    ``from_flat`` / ``to_flat`` carry the settings as flat keys (scenario
+    configs, planner queries).
+    """
+
+    chunk: int = DEFAULT_CHUNK
+    precision: float = DEFAULT_PRECISION
+    shard: object = True
+    use_kernel: bool = False
+    k_max: object = "auto"
+
+    FIELDS = ("chunk", "precision", "shard", "use_kernel", "k_max")
+
+    def __post_init__(self) -> None:
+        if self.chunk < 1:
+            raise ValueError(f"chunk must be >= 1, got {self.chunk}")
+        sketch_bins(self.precision)             # validates precision
+        k = self.k_max
+        if isinstance(k, list):                 # JSON spelling of a triple
+            k = tuple(k)
+            object.__setattr__(self, "k_max", k)
+        whole = (int, jnp.integer)
+        if not (k is None or k == "auto" or isinstance(k, whole)
+                or (isinstance(k, tuple) and len(k) == 3
+                    and all(isinstance(x, whole) for x in k))):
+            raise ValueError(f"k_max must be None, 'auto', an int or a "
+                             f"(k1, k2c, k2f) triple, got {self.k_max!r}")
+
+    @classmethod
+    def from_flat(cls, d: Dict, base: Optional["StreamSettings"] = None
+                  ) -> Tuple["StreamSettings", Dict]:
+        """Lift the setting keys of a flat dict over ``base`` (default:
+        streaming's own settings); returns ``(settings, other keys)``.  A
+        null ``chunk`` or ``precision`` keeps the base value."""
+        base = cls() if base is None else base
+        rest = {k: v for k, v in d.items() if k not in cls.FIELDS}
+        lifted = {k: d[k] for k in cls.FIELDS if k in d
+                  and not (d[k] is None and k in ("chunk", "precision"))}
+        return (replace(base, **lifted) if lifted else base), rest
+
+    def to_flat(self) -> Dict:
+        """The settings as flat keys, the inverse of ``from_flat``."""
+        return {k: getattr(self, k) for k in self.FIELDS}
+
+    def devices(self) -> int:
+        """How many devices a stream's trial axis spans under ``shard``."""
+        if self.shard is True:
+            return len(jax.devices())
+        return self.shard.shape[psharding.TRIAL_AXIS] if self.shard else 1
+
+
 def _resolve_mesh(shard):
     """``shard=True`` -> the global trial mesh (falls back to unsharded on
     a single device, with a ``UserWarning`` so multi-process launch scripts
@@ -827,10 +897,11 @@ def _resolve_mesh(shard):
 
 
 def _resolve_k_sat(table, k_max, n: int):
-    """Normalize the ``k_max`` knob to a static ``(k1, k2c, k2f)`` tuple
-    (or None = full-sort reference path).  ``"auto"`` derives the depths
-    from the concrete table (``engine.saturation_depths``); an int caps all
-    three phases; an explicit 3-tuple is clipped to [1, n]."""
+    """Normalize a ``StreamSettings.k_max`` (its form already checked) to
+    a static ``(k1, k2c, k2f)`` tuple (or None = full-sort reference
+    path).  ``"auto"`` derives the depths from the concrete table
+    (``engine.saturation_depths``); an int caps all three phases; an
+    explicit 3-tuple is clipped to [1, n]."""
     if k_max is None:
         return None
     if k_max == "auto":
@@ -838,9 +909,6 @@ def _resolve_k_sat(table, k_max, n: int):
     if isinstance(k_max, int):
         k_max = (k_max, k_max, k_max)
     ks = tuple(int(k) for k in k_max)
-    if len(ks) != 3:
-        raise ValueError(f"k_max must be None, 'auto', an int or a "
-                         f"(k1, k2c, k2f) triple, got {k_max!r}")
     depths = engine.saturation_depths(table)
     for req, need in zip(ks, depths):
         if req < need:
@@ -851,8 +919,8 @@ def _resolve_k_sat(table, k_max, n: int):
 
 
 def _stream_entry(path: str, key, table, delay, offsets, *, n, k_proposers,
-                  trials, chunk, precision, use_kernel, shard, k_max="auto",
-                  regimes=None, recovery="coordinated") -> StreamSummary:
+                  trials, stream: StreamSettings, regimes=None,
+                  recovery="coordinated") -> StreamSummary:
     """One stream pass under the host span ``repro.stream.<path>``: checks,
     sort-free depths, pair layout and the asynchronous dispatch of
     ``_stream``."""
@@ -861,15 +929,14 @@ def _stream_entry(path: str, key, table, delay, offsets, *, n, k_proposers,
         engine._check_recovery(recovery)
         if trials < 1:
             raise ValueError(f"trials must be >= 1, got {trials}")
-        if chunk < 1:
-            raise ValueError(f"chunk must be >= 1, got {chunk}")
-        sketch_bins(precision)             # validates precision
+        chunk, precision = stream.chunk, stream.precision
+        use_kernel = stream.use_kernel
         if regimes is not None:
             if isinstance(regimes, dict):
                 regimes = MarkovRegimes.from_config(regimes, n)
             regimes = regimes.validate().bound(
                 delay if delay is not None else default_delay())
-        mesh = _resolve_mesh(shard)
+        mesh = _resolve_mesh(stream.shard)
         if mesh is None and trials <= chunk and regimes is None:
             # The materializing path IS the T <= chunk special case: same
             # compile as direct engine calls, bit-identical draws, reduced.
@@ -886,7 +953,7 @@ def _stream_entry(path: str, key, table, delay, offsets, *, n, k_proposers,
                     engine.classic_path(key, table, delay, n=n,
                                         samples=trials), fast=False)
             return StreamSummary.from_outcomes(out, precision)
-        k_sat = _resolve_k_sat(table, k_max, n)
+        k_sat = _resolve_k_sat(table, stream.k_max, n)
         layout = (_card_layout(table, recovery)
                   if "q" in table and k_sat is not None else _dummy_layout())
         ndev = 1 if mesh is None else mesh.shape[psharding.TRIAL_AXIS]
@@ -918,6 +985,8 @@ def race_stream(key, table, offsets, delay=None, *, n: int, k_proposers: int,
     ``lax.scan`` reduction into a ``StreamSummary``, trial axis sharded
     over local devices when ``shard`` (a bool or an explicit 1-D mesh).
     One compile per (table shape, chunk count); ``trials`` is traced.
+    ``chunk`` / ``precision`` / ``shard`` / ``use_kernel`` / ``k_max`` are
+    the fields of ``StreamSettings``.
 
     ``k_max`` (default ``"auto"``) selects the sort-free lowering
     (DESIGN.md §9): top-k arrival prefixes at the table's saturation depths
@@ -935,11 +1004,11 @@ def race_stream(key, table, offsets, delay=None, *, n: int, k_proposers: int,
     ``recovery`` (static, ``engine.RECOVERY_MODES``) selects the
     collision-recovery rule; each mode is its own compile of the same
     stream path (one per mode, not per system)."""
+    stream = StreamSettings(chunk=chunk, precision=precision, shard=shard,
+                            use_kernel=use_kernel, k_max=k_max)
     return _stream_entry("race", key, table, delay, offsets, n=n,
-                         k_proposers=k_proposers, trials=trials, chunk=chunk,
-                         precision=precision, use_kernel=use_kernel,
-                         shard=shard, k_max=k_max, regimes=regimes,
-                         recovery=recovery)
+                         k_proposers=k_proposers, trials=trials,
+                         stream=stream, regimes=regimes, recovery=recovery)
 
 
 def fast_path_stream(key, table, delay=None, *, n: int, trials: int,
@@ -950,10 +1019,11 @@ def fast_path_stream(key, table, delay=None, *, n: int, trials: int,
     """Streamed conflict-free fast path (k=1): decided instances count as
     fast-path commits, lost ones as undecided.  ``k_max`` / ``regimes`` as
     in ``race_stream``."""
+    stream = StreamSettings(chunk=chunk, precision=precision, shard=shard,
+                            k_max=k_max)
     return _stream_entry("fast_path", key, table, delay, None, n=n,
-                         k_proposers=1, trials=trials, chunk=chunk,
-                         precision=precision, use_kernel=False, shard=shard,
-                         k_max=k_max, regimes=regimes)
+                         k_proposers=1, trials=trials, stream=stream,
+                         regimes=regimes)
 
 
 def classic_path_stream(key, table, delay=None, *, n: int, trials: int,
@@ -964,7 +1034,8 @@ def classic_path_stream(key, table, delay=None, *, n: int, trials: int,
     """Streamed leader-relayed classic path: decided instances count as
     recoveries (there is no fast path to reach).  ``k_max`` / ``regimes``
     as in ``race_stream``."""
+    stream = StreamSettings(chunk=chunk, precision=precision, shard=shard,
+                            k_max=k_max)
     return _stream_entry("classic_path", key, table, delay, None, n=n,
-                         k_proposers=1, trials=trials, chunk=chunk,
-                         precision=precision, use_kernel=False, shard=shard,
-                         k_max=k_max, regimes=regimes)
+                         k_proposers=1, trials=trials, stream=stream,
+                         regimes=regimes)

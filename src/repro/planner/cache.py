@@ -1,21 +1,15 @@
 """Warm compiled-engine pool for planner queries (DESIGN.md §11).
 
-``montecarlo.streaming._stream`` compiles once per engine *geometry* —
-the static signature (table shapes, pair-layout width, chunk count,
-precision, resolved saturation depths, mesh) — and JAX's jit cache keeps
-that compile warm for the life of the process.  What a long-lived planner
-needs on top is bookkeeping and memoization:
-
-  EngineKey     the geometry a scoring query lowers to, computed host-side
-                without touching the engine — two queries with equal keys
-                are guaranteed to re-enter the same compiles.
-  EngineCache   routes ``frontier.score.score_systems`` calls through a
-                per-key ledger (queries seen, compiles actually paid,
-                measured via the ``engine.TRACE_COUNTS`` delta around the
-                call) plus an LRU of full ``FrontierResult``s keyed by a
-                *content* fingerprint (table bytes + delay leaves + every
-                parameter), so a bit-identical repeat query returns
-                without running the engine at all.
+``montecarlo.streaming._stream`` compiles once per engine geometry (table
+shapes, chunking, precision, resolved saturation depths, mesh — all
+decided in ``streaming``), and JAX's jit cache keeps that compile warm
+for the life of the process.  What a long-lived planner needs on top is
+bookkeeping and memoization: ``EngineCache`` routes scoring queries
+through ``frontier.score`` while counting the compiles each actually paid
+(the ``engine.TRACE_COUNTS`` delta around the call), and keeps an LRU of
+full ``FrontierResult``s keyed by a *content* fingerprint (table bytes +
+delay leaves + the stream settings + every other parameter), so a
+bit-identical repeat query returns without running the engine at all.
 
 The planner service keeps one ``EngineCache`` for its whole lifetime; the
 successive-halving search threads one through all its rungs.  The
@@ -27,14 +21,19 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from dataclasses import dataclass, replace
-from typing import Dict, Optional, Sequence, Tuple
+from dataclasses import replace
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
 import jax
 
+from repro.frontier import score as fscore
 from repro.montecarlo import engine, streaming
+from repro.montecarlo.regimes import MarkovRegimes
+
+# The planner scores on one device unless a query asks for the trial mesh.
+DEFAULT_STREAM = replace(fscore.DEFAULT_STREAM, shard=False)
 
 # The base per-path trace counters.  The ``*_sortfree`` / ``*_fused`` keys
 # increment ALONGSIDE their base key (they pin which lowering ran), so
@@ -46,77 +45,6 @@ BASE_TRACE_KEYS = ("race", "fast_path", "classic_path",
 def trace_total() -> int:
     """Total engine traces so far (jit cache misses across all paths)."""
     return sum(engine.TRACE_COUNTS[k] for k in BASE_TRACE_KEYS)
-
-
-@dataclass(frozen=True)
-class EngineKey:
-    """The static geometry one scoring query lowers to.
-
-    Mirrors ``streaming._stream``'s static argnames plus everything that
-    feeds them: equal keys ⇒ the query re-enters already-traced compiles
-    (shapes and statics identical; table *contents* are traced).  The
-    materializing T <= chunk fallback jits on ``samples`` instead of a
-    chunk count, so ``mode`` + ``n_chunks`` carries either geometry.
-    """
-
-    table_sig: Tuple[Tuple[str, Tuple[int, ...], str], ...]
-    layout_pairs: int               # P of the cardinality pair layout (0: n/a)
-    n: int
-    k_proposers: int
-    chunk: int
-    n_chunks: int                   # streamed: chunks; materializing: samples
-    mode: str                       # "stream" | "materialize"
-    precision: float
-    k_sat: Optional[Tuple[int, int, int]]
-    use_kernel: bool
-    ndev: int
-    # Markov regime modulation changes the lowered scan geometry (R regime
-    # environments, epoch length in trials); (R, epoch_trials) or None.
-    regimes_sig: Optional[Tuple[int, int]] = None
-    # Collision-recovery rule: static on the stream jits, AND it changes the
-    # cardinality pair layout (q2c vs q2f columns), so equal keys require it.
-    recovery: str = "coordinated"
-
-
-def _resolve_ndev(shard) -> int:
-    """Device count a ``shard`` setting will actually run on (without the
-    loud single-device warning — key computation is not a run)."""
-    if shard is False or shard is None:
-        return 1
-    if shard is True:
-        n = len(jax.devices())
-        return n if n > 1 else 1
-    from repro.parallel import sharding as psharding
-    return shard.shape[psharding.TRIAL_AXIS]
-
-
-def engine_key(table: Dict, *, n: int, k_proposers: int, trials: int,
-               chunk: int, precision: float, shard, use_kernel: bool,
-               k_max, regimes=None,
-               recovery: str = "coordinated") -> EngineKey:
-    """Compute the warm-pool key for one scoring query, host-side."""
-    sig = tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                       for k, v in table.items()))
-    ndev = _resolve_ndev(shard)
-    if regimes is None and ndev == 1 and trials <= chunk:
-        # materializing fallback: ``samples`` itself is the jit static
-        return EngineKey(sig, 0, n, k_proposers, chunk, trials,
-                         "materialize", precision, None, use_kernel, 1,
-                         recovery=recovery)
-    k_sat = streaming._resolve_k_sat(table, k_max, n)
-    pairs = 0
-    if "q" in table and k_sat is not None:
-        # the recovery rule picks which q-column pairs with q1 in the
-        # cardinality layout, so the pair count is rule-dependent
-        cols = [0, 1] if recovery == "coordinated" else [0, 2]
-        pairs = int(np.unique(np.asarray(table["q"])[:, cols],
-                              axis=0).shape[0])
-    per_device = -(-trials // ndev)
-    n_chunks = -(-per_device // chunk)
-    rsig = (None if regimes is None
-            else (len(regimes.names), int(regimes.epoch_trials)))
-    return EngineKey(sig, pairs, n, k_proposers, chunk, n_chunks, "stream",
-                     precision, k_sat, use_kernel, ndev, rsig, recovery)
 
 
 def _delay_token(delay) -> bytes:
@@ -137,105 +65,83 @@ def _delay_token(delay) -> bytes:
 class EngineCache:
     """Warm engine pool + result memo for a long-lived planner process.
 
-    ``score`` has the same semantics as ``frontier.score.score_systems``
-    (same arguments, same ``FrontierResult``, bit-identical values) with
-    three additions: a per-``EngineKey`` ledger of queries vs compiles
-    paid, an ``engine_compiles`` attribute on the returned result (the
-    TRACE_COUNTS delta this call caused), and an LRU memo of results so a
-    bit-identical repeat query skips the engine entirely (memo hits report
-    ``engine_compiles == 0`` without even entering jit dispatch).
+    ``score`` has the semantics of ``frontier.score.score_systems`` (same
+    ``FrontierResult``, bit-identical values; the stream settings come as
+    one ``stream``) with two additions: an ``engine_compiles`` attribute
+    on the returned result (the TRACE_COUNTS delta this call caused), and
+    an LRU memo of results so a bit-identical repeat query skips the
+    engine entirely (memo hits report ``engine_compiles == 0`` without
+    even entering jit dispatch).
     """
 
     def __init__(self, memo_size: int = 64):
         self.memo_size = memo_size
-        self.stats: Dict[EngineKey, Dict[str, int]] = {}
         self._memo: "OrderedDict[bytes, object]" = OrderedDict()
+        self.engine_compiles = 0
         self.memo_hits = 0
         self.memo_misses = 0
 
-    # -- introspection -----------------------------------------------------
-    def warm(self, key: EngineKey) -> bool:
-        """Has this geometry been scored (hence traced) before?"""
-        return key in self.stats
-
-    @property
-    def total_compiles(self) -> int:
-        return sum(s["compiles"] for s in self.stats.values())
-
     def stats_dict(self) -> Dict[str, float]:
-        return {"engine_keys": float(len(self.stats)),
-                "engine_compiles": float(self.total_compiles),
+        return {"engine_compiles": float(self.engine_compiles),
                 "memo_hits": float(self.memo_hits),
                 "memo_misses": float(self.memo_misses)}
 
     # -- the one entry point ----------------------------------------------
     def score(self, systems: Sequence, *, trials: int,
               n: Optional[int] = None, k_proposers: int = 2,
-              delta_ms: Optional[float] = None, delay=None,
-              chunk: Optional[int] = None, precision: Optional[float] = None,
-              shard=False, use_kernel: bool = False, k_max="auto",
+              delta_ms: float = fscore.DEFAULT_DELTA_MS, delay=None,
+              stream: streaming.StreamSettings = DEFAULT_STREAM,
               seed: int = 0, regimes=None, recovery: str = "coordinated",
               axes=None):
-        from repro.frontier import score as fscore
-        from repro.montecarlo.regimes import MarkovRegimes
+        systems = list(systems)
+        with jax.profiler.TraceAnnotation("repro.score", systems=len(systems),
+                                          trials=trials, seed=seed):
+            low = fscore._lower(systems, n)
+            if isinstance(regimes, dict):    # serialized chain: resolve once
+                regimes = MarkovRegimes.from_config(regimes, low.n)
+            fp = _fingerprint(low, stream=stream, k_proposers=k_proposers,
+                              trials=trials, seed=seed, delta_ms=delta_ms,
+                              delay=delay, regimes=regimes,
+                              recovery=recovery, axes=axes)
+            hit = self._memo.get(fp)
+            if hit is not None:
+                self._memo.move_to_end(fp)
+                self.memo_hits += 1
+                out = replace(hit)              # fresh wrapper, shared arrays
+                out.engine_compiles = 0
+                return out
+            self.memo_misses += 1
 
-        delta_ms = (fscore.DEFAULT_DELTA_MS if delta_ms is None
-                    else delta_ms)
-        chunk = fscore.DEFAULT_CHUNK if chunk is None else chunk
-        precision = (streaming.DEFAULT_PRECISION if precision is None
-                     else precision)
+            before = trace_total()
+            result = fscore._score(low, trials=trials,
+                                   k_proposers=k_proposers,
+                                   delta_ms=delta_ms, delay=delay,
+                                   stream=stream, seed=seed, regimes=regimes,
+                                   recovery=recovery, axes=axes)
+            result.engine_compiles = trace_total() - before
+            self.engine_compiles += result.engine_compiles
 
-        masks, _, n = fscore._as_masks(list(systems), n)
-        if isinstance(regimes, dict):        # serialized chain: resolve once
-            regimes = MarkovRegimes.from_config(regimes, n)
-        table = engine.build_mask_table(masks)
-        key = engine_key(table, n=n, k_proposers=k_proposers, trials=trials,
-                         chunk=chunk, precision=precision, shard=shard,
-                         use_kernel=use_kernel, k_max=k_max, regimes=regimes,
-                         recovery=recovery)
-        labels = tuple(m.label or f"system{i}" for i, m in enumerate(masks))
-        fp = self._fingerprint(table, key, labels=labels, trials=trials,
-                               seed=seed, delta_ms=delta_ms, delay=delay,
-                               regimes=regimes, axes=axes)
-        hit = self._memo.get(fp)
-        if hit is not None:
-            self._memo.move_to_end(fp)
-            self.memo_hits += 1
-            st = self.stats.setdefault(key, {"queries": 0, "compiles": 0})
-            st["queries"] += 1
-            out = replace(hit)                  # fresh wrapper, shared arrays
-            out.engine_compiles = 0
-            return out
-        self.memo_misses += 1
+            self._memo[fp] = result
+            while len(self._memo) > self.memo_size:
+                self._memo.popitem(last=False)
+            return result
 
-        before = trace_total()
-        result = fscore.score_systems(
-            list(systems), trials=trials, n=n, k_proposers=k_proposers,
-            delta_ms=delta_ms, delay=delay, chunk=chunk, precision=precision,
-            shard=shard, use_kernel=use_kernel, k_max=k_max, seed=seed,
-            regimes=regimes, recovery=recovery, axes=axes)
-        compiles = trace_total() - before
-        st = self.stats.setdefault(key, {"queries": 0, "compiles": 0})
-        st["queries"] += 1
-        st["compiles"] += compiles
-        result.engine_compiles = compiles
 
-        self._memo[fp] = result
-        while len(self._memo) > self.memo_size:
-            self._memo.popitem(last=False)
-        return result
-
-    # -- internals ---------------------------------------------------------
-    def _fingerprint(self, table: Dict, key: EngineKey, *,
-                     labels: Tuple[str, ...], trials: int, seed: int,
-                     delta_ms: float, delay, axes, regimes=None) -> bytes:
-        h = hashlib.sha256(repr(key).encode())
-        h.update(repr((labels, trials, seed, delta_ms)).encode())
-        for name in sorted(table):
-            arr = np.asarray(table[name])
-            h.update(name.encode())
-            h.update(arr.tobytes())
-        h.update(_delay_token(delay))
-        h.update(_delay_token(regimes))     # content token works per-pytree
-        h.update(repr(tuple(axes) if axes is not None else None).encode())
-        return h.digest()
+def _fingerprint(low, *, stream: streaming.StreamSettings, k_proposers: int,
+                 trials: int, seed: int, delta_ms: float, delay, regimes,
+                 recovery: str, axes) -> bytes:
+    """Content hash of one scoring query: the table, the labels, the
+    stream settings and the devices ``shard`` spans, the delay and regime
+    models by their leaves, and every other parameter."""
+    h = hashlib.sha256(repr((low.n, low.labels, stream, stream.devices(),
+                             k_proposers, trials, seed, delta_ms,
+                             recovery)).encode())
+    for name in sorted(low.table):
+        arr = np.asarray(low.table[name])
+        h.update(name.encode())
+        h.update(repr((arr.shape, str(arr.dtype))).encode())
+        h.update(arr.tobytes())
+    h.update(_delay_token(delay))
+    h.update(_delay_token(regimes))         # content token works per-pytree
+    h.update(repr(tuple(axes) if axes is not None else None).encode())
+    return h.digest()

@@ -48,7 +48,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.frontier import score as fscore
 from repro.frontier.pareto import Axis, _REL_MIN, pareto_mask
+from repro.montecarlo.streaming import StreamSettings
+
+from .cache import DEFAULT_STREAM, EngineCache
 
 # Margin multiplier: 1.0 = one sketch quantization cell plus ~1 sigma of
 # Monte-Carlo noise per stochastic axis.  Common random numbers mean both
@@ -331,10 +335,9 @@ def successive_halving(candidates: Sequence, schedule: Sequence[Rung],
 def search(systems: Sequence, *, final_trials: int = 1_000_000,
            schedule: Optional[Sequence[Rung]] = None,
            n: Optional[int] = None, k_proposers: int = 2,
-           delta_ms: Optional[float] = None, delay=None,
-           chunk: Optional[int] = None, precision: Optional[float] = None,
-           shard: bool = False, use_kernel: bool = False, k_max="auto",
-           seed: int = 0, slack: float = DEFAULT_SLACK,
+           delta_ms: float = fscore.DEFAULT_DELTA_MS, delay=None,
+           stream: StreamSettings = DEFAULT_STREAM, seed: int = 0,
+           slack: float = DEFAULT_SLACK,
            regimes=None, recovery: str = "coordinated",
            cache=None) -> SearchResult:
     """Successive-halving search through the streamed scorer.
@@ -343,26 +346,20 @@ def search(systems: Sequence, *, final_trials: int = 1_000_000,
     systems, or raw masks (the same front door as ``score_systems``); the
     scorer runs every rung through ``planner.cache.EngineCache`` so repeat
     table geometries re-enter warm compiles (pass ``cache`` to share the
-    pool across searches — the planner service does).  All rungs score
-    with the SAME seed/chunk/precision, so the final rung's per-system
-    values are bit-identical to a direct ``score_systems`` call over the
-    full space at ``final_trials`` — the search changes *which* systems
-    get the full budget, never their scores.
+    pool across searches — the planner service does).  ``stream`` is the
+    engine's ``streaming.StreamSettings``.
+    All rungs score with the SAME seed and stream settings, so the final
+    rung's per-system values are bit-identical to a direct
+    ``score_systems`` call over the full space at ``final_trials`` — the
+    search changes *which* systems get the full budget, never their
+    scores.
     """
-    from repro.frontier import score as fscore
-    from .cache import EngineCache
-
     if schedule is None:
         schedule = default_schedule(final_trials, slack=slack)
     cache = cache if cache is not None else EngineCache()
-    kwargs = dict(
-        n=n, k_proposers=k_proposers,
-        delta_ms=(delta_ms if delta_ms is not None
-                  else fscore.DEFAULT_DELTA_MS),
-        delay=delay,
-        chunk=chunk if chunk is not None else fscore.DEFAULT_CHUNK,
-        precision=precision, shard=shard, use_kernel=use_kernel,
-        k_max=k_max, seed=seed, regimes=regimes, recovery=recovery)
+    kwargs = dict(n=n, k_proposers=k_proposers, delta_ms=delta_ms,
+                  delay=delay, stream=stream, seed=seed, regimes=regimes,
+                  recovery=recovery)
     scorer = lambda members, trials: cache.score(members, trials=trials,
                                                  **kwargs)
     return successive_halving(list(systems), schedule, scorer)

@@ -42,8 +42,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.quorum import QuorumSpec
+from repro.montecarlo.streaming import StreamSettings
 
-from .cache import EngineCache, _delay_token
+from .cache import DEFAULT_STREAM, EngineCache, _delay_token
 from .search import (DEFAULT_SLACK, Rung, SearchResult, default_schedule,
                      search)
 
@@ -72,7 +73,9 @@ class PlanQuery:
     one of ``race_p999_ms`` (default), ``fast_p50_ms``, ``p_recovery``
     (all minimized).  ``trials`` is the FINAL successive-halving budget;
     the schedule below it is derived (``search.default_schedule``) unless
-    ``schedule`` pins explicit ``[trials, slack]`` rungs.
+    ``schedule`` pins explicit ``[trials, slack]`` rungs.  ``stream`` is
+    the engine's ``StreamSettings``; over the wire its fields travel as
+    flat keys (``"chunk"``, ``"precision"``, ...), lifted by ``from_dict``.
     """
 
     n: int = 11
@@ -82,12 +85,8 @@ class PlanQuery:
     trials: int = DEFAULT_TRIALS
     objective: str = "race_p999_ms"
     schedule: Optional[Tuple[Tuple[int, float], ...]] = None
-    chunk: Optional[int] = None
-    precision: Optional[float] = None
+    stream: StreamSettings = DEFAULT_STREAM
     seed: int = 0
-    shard: bool = False
-    use_kernel: bool = False
-    k_max: object = "auto"
     slack: float = DEFAULT_SLACK
 
     def __post_init__(self) -> None:
@@ -108,12 +107,14 @@ class PlanQuery:
 
     @classmethod
     def from_dict(cls, d: Dict) -> "PlanQuery":
-        known = {f for f in cls.__dataclass_fields__}
-        unknown = set(d) - known
+        stream, rest = StreamSettings.from_flat(
+            d, d.get("stream", DEFAULT_STREAM))
+        known = set(cls.__dataclass_fields__)
+        unknown = set(rest) - known
         if unknown:
             raise ValueError(f"unknown query fields {sorted(unknown)}; "
-                             f"known: {sorted(known)}")
-        return cls(**d)
+                             f"known: {sorted(known | set(stream.FIELDS))}")
+        return cls(**dict(rest, stream=stream))
 
 
 def resolve_workload(workload):
@@ -211,27 +212,20 @@ class Planner:
         wl = resolve_workload(q.workload)
         racing = wl.k_proposers >= 2
         from repro.frontier import score as fscore
-        from repro.montecarlo import streaming
         k_eff = wl.k_proposers if racing else 2
         d_eff = wl.delta_ms if racing else fscore.DEFAULT_DELTA_MS
-        # None knobs resolve to the scorer's defaults before keying, so a
-        # query spelling the default explicitly still shares the search
-        chunk = q.chunk if q.chunk is not None else fscore.DEFAULT_CHUNK
-        precision = (q.precision if q.precision is not None
-                     else streaming.DEFAULT_PRECISION)
         return (q.n, q.family, k_eff, d_eff,
                 _delay_token(wl.delay_for(q.n)),
                 _delay_token(wl.regimes_for(q.n)),
-                q.trials, q.schedule,
-                chunk, precision, q.seed, bool(q.shard), q.use_kernel,
-                repr(q.k_max), q.slack, wl.recovery)
+                q.trials, q.schedule, q.stream, q.seed, q.slack,
+                wl.recovery)
 
     # -- planning ----------------------------------------------------------
     def plan(self, query=None, **kw) -> PlanResult:
         """Answer one query (a ``PlanQuery``, a dict, or keyword fields)."""
         if query is None:
-            query = PlanQuery(**kw)
-        elif isinstance(query, dict):
+            query = kw
+        if isinstance(query, dict):
             query = PlanQuery.from_dict(query)
         return self.plan_group([query])[0]
 
@@ -281,8 +275,7 @@ class Planner:
             members, final_trials=q.trials, schedule=schedule, n=q.n,
             k_proposers=wl.k_proposers if racing else 2,
             delta_ms=wl.delta_ms if racing else fscore.DEFAULT_DELTA_MS,
-            delay=wl.delay_for(q.n), chunk=q.chunk, precision=q.precision,
-            shard=q.shard, use_kernel=q.use_kernel, k_max=q.k_max,
+            delay=wl.delay_for(q.n), stream=q.stream,
             seed=q.seed, slack=q.slack, regimes=wl.regimes_for(q.n),
             recovery=wl.recovery, cache=self.engines)
         self._searches[gkey] = sr

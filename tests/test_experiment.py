@@ -10,6 +10,7 @@ from repro.api import BACKENDS, Experiment, Results, Workload, sweep
 from repro.core.quorum import (ExplicitQuorumSystem, QuorumSpec,
                                WeightedQuorumSystem)
 from repro.montecarlo import engine
+from repro.montecarlo.streaming import StreamSettings
 
 # Small enough for the modelcheck backend, rich enough to span all three
 # system families.
@@ -180,7 +181,8 @@ def test_streamed_experiment_matches_materialized_summary():
     kw = dict(systems=SYSTEMS, workload=Workload.race(k=2, delta_ms=0.3),
               compute_fault_tolerance=False)
     mat = Experiment(samples=40_000, **kw).run("montecarlo")
-    stream = Experiment(trials=40_000, chunk=8_192, **kw).run("montecarlo")
+    stream = Experiment(trials=40_000, stream=StreamSettings(chunk=8_192),
+                        **kw).run("montecarlo")
     assert stream.raw is None and stream.stream is not None
     assert set(mat.summary) <= set(stream.summary)
     assert "p999_ms" in stream.summary
@@ -195,8 +197,8 @@ def test_streamed_experiment_matches_materialized_summary():
 
 def test_streamed_experiment_is_a_pytree_with_stream_state():
     r = Experiment(systems=[QuorumSpec(5, 4, 2, 4)], trials=3_000,
-                   chunk=1_024, compute_fault_tolerance=False
-                   ).run("montecarlo")
+                   stream=StreamSettings(chunk=1_024),
+                   compute_fault_tolerance=False).run("montecarlo")
     assert int(r.stream.n_trials[0]) == 3_000
     leaves, treedef = jax.tree_util.tree_flatten(r)
     r2 = jax.tree_util.tree_unflatten(treedef, leaves)

@@ -387,15 +387,16 @@ def test_experiment_frontier_front_door():
     the experiment's systems and config."""
     from repro.api import Experiment, Workload, frontier
     systems = [QuorumSpec.paper_headline(11), QuorumSpec.fast_paxos(11)]
+    stream = streaming.StreamSettings(chunk=8_192, shard=False)
     exp = Experiment(systems=systems,
                      workload=Workload.race(k=2, delta_ms=0.2),
-                     trials=20_000, chunk=8_192, shard=False,
+                     trials=20_000, stream=stream,
                      compute_fault_tolerance=False)
     fr = exp.frontier()
     assert fr.labels == ("card[9,3,7]", "card[6,6,9]")
     # the two landmarks trade fault tolerance for latency: both survive
     assert fr.frontier_labels == fr.labels
-    fr2 = frontier(systems, trials=20_000, chunk=8_192, shard=False)
+    fr2 = frontier(systems, trials=20_000, stream=stream)
     np.testing.assert_array_equal(np.asarray(fr2.values),
                                   np.asarray(fr.values))
 
@@ -408,7 +409,9 @@ def test_experiment_frontier_honors_faults():
     from repro.api import Experiment, Workload
     spec = QuorumSpec.paper_headline(11)          # q2f=7: tolerates 4
     base = Experiment(systems=[spec], workload=Workload.race(k=2),
-                      trials=4_000, chunk=8_192, shard=False,
+                      trials=4_000,
+                      stream=streaming.StreamSettings(chunk=8_192,
+                                                      shard=False),
                       compute_fault_tolerance=False)
     import dataclasses
     faulty = dataclasses.replace(base, faults=(0, 1, 2, 3, 4))

@@ -26,8 +26,9 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro.frontier import cardinality_family, default_axes, pareto_mask
 from repro.frontier.score import AXIS_NAMES, score_systems
 from repro.montecarlo import engine
+from repro.montecarlo.streaming import StreamSettings
 from repro.planner import (EngineCache, PlanQuery, Planner, PlannerServer,
-                           Rung, default_schedule, engine_key,
+                           Rung, default_schedule,
                            prune_survivors, query_server, search,
                            successive_halving)
 from repro.planner.search import quantile_margin_cells, rate_margin
@@ -37,6 +38,7 @@ from repro.planner.search import quantile_margin_cells, rate_margin
 # ---------------------------------------------------------------------------
 
 # Small, fast geometry for cache/service tests.
+SMALL_STREAM = StreamSettings(chunk=4_096, shard=False)
 SMALL = dict(n=7, chunk=4_096, shard=False, seed=0)
 SMALL_SCHEDULE = ((2_000, 2.0), (20_000, 2.0))
 
@@ -274,33 +276,14 @@ def _small_members():
     return cardinality_family(7)
 
 
-def test_engine_key_modes():
-    table = engine.build_mask_table([m.masks() for m in _small_members()])
-    streamed = engine_key(table, n=7, k_proposers=2, trials=50_000,
-                          chunk=4_096, precision=0.01, shard=False,
-                          use_kernel=False, k_max="auto")
-    assert streamed.mode == "stream"
-    assert streamed.n_chunks == -(-50_000 // 4_096)
-    assert streamed.layout_pairs > 0          # cardinality pair layout
-    mat = engine_key(table, n=7, k_proposers=2, trials=1_000,
-                     chunk=4_096, precision=0.01, shard=False,
-                     use_kernel=False, k_max="auto")
-    assert mat.mode == "materialize" and mat.n_chunks == 1_000
-    # same geometry, different trials but same chunk count -> same key
-    same = engine_key(table, n=7, k_proposers=2, trials=52_000,
-                      chunk=4_096, precision=0.01, shard=False,
-                      use_kernel=False, k_max="auto")
-    assert same == streamed
-
-
 def test_engine_cache_second_same_shape_scores_zero_compiles():
     cache = EngineCache()
     members = _small_members()
-    r1 = cache.score(members, trials=30_000, n=7, chunk=4_096, shard=False,
+    r1 = cache.score(members, trials=30_000, n=7, stream=SMALL_STREAM,
                      seed=0)
     assert r1.engine_compiles > 0             # cold: fast + race traces
     before = dict(engine.TRACE_COUNTS)
-    r2 = cache.score(members, trials=30_000, n=7, chunk=4_096, shard=False,
+    r2 = cache.score(members, trials=30_000, n=7, stream=SMALL_STREAM,
                      seed=0)
     assert engine.TRACE_COUNTS == before      # memo hit: engine untouched
     assert r2.engine_compiles == 0
@@ -309,7 +292,7 @@ def test_engine_cache_second_same_shape_scores_zero_compiles():
                                   np.asarray(r2.values))
     # different seed: memo miss, but the jit cache stays warm -> zero
     # NEW compiles even though the engine actually runs
-    r3 = cache.score(members, trials=30_000, n=7, chunk=4_096, shard=False,
+    r3 = cache.score(members, trials=30_000, n=7, stream=SMALL_STREAM,
                      seed=1)
     assert r3.engine_compiles == 0
     assert cache.memo_misses == 2
@@ -321,13 +304,30 @@ def test_engine_cache_scores_match_direct():
     """Routing through the cache changes bookkeeping, never values."""
     cache = EngineCache()
     members = _small_members()[:10]
-    via = cache.score(members, trials=9_000, n=7, chunk=4_096, shard=False,
+    via = cache.score(members, trials=9_000, n=7, stream=SMALL_STREAM,
                       seed=3)
     direct = score_systems(members, trials=9_000, n=7, chunk=4_096,
                            shard=False, seed=3)
     np.testing.assert_array_equal(np.asarray(via.values),
                                   np.asarray(direct.values))
     assert via.labels == direct.labels
+
+
+def test_engine_cache_builds_the_table_once_per_query(monkeypatch):
+    """A planner query encodes its masks and builds its mask table once,
+    and scores that table (a memo hit builds it once more, to look up)."""
+    real, built = engine.build_mask_table, []
+
+    def counting(masks, **kw):
+        built.append(len(masks))
+        return real(masks, **kw)
+    monkeypatch.setattr(engine, "build_mask_table", counting)
+    cache = EngineCache()
+    members = _small_members()[:6]
+    cache.score(members, trials=3_000, n=7, stream=SMALL_STREAM, seed=0)
+    assert built == [6]
+    cache.score(members, trials=3_000, n=7, stream=SMALL_STREAM, seed=0)
+    assert built == [6, 6] and cache.memo_hits == 1
 
 
 # ---------------------------------------------------------------------------
@@ -337,7 +337,8 @@ def test_engine_cache_scores_match_direct():
 def test_search_small_matches_direct_frontier():
     members = _small_members()
     sr = search(members, final_trials=20_000,
-                schedule=(Rung(2_000), Rung(20_000)), **SMALL)
+                schedule=(Rung(2_000), Rung(20_000)), n=7,
+                stream=SMALL_STREAM, seed=0)
     direct = score_systems(members, trials=20_000, **{
         k: v for k, v in SMALL.items()})
     assert set(sr.frontier_labels) == set(direct.frontier_labels)
@@ -453,7 +454,7 @@ def test_api_plan_and_experiment_plan():
 
     exp = Experiment(systems=[QuorumSpec.paper_headline(7)],
                      workload=Workload.race(k=2, delta_ms=0.2),
-                     chunk=4_096, shard=False)
+                     stream=SMALL_STREAM)
     r2 = exp.plan(faults={"classic": 1}, trials=20_000,
                   schedule=SMALL_SCHEDULE, planner=planner)
     assert r2.ok
@@ -528,7 +529,8 @@ def test_launch_local_does_not_retry_other_failures(monkeypatch):
 def test_acceptance_n11_exact_frontier_under_budget():
     members, direct = _truth()
     sr = search(members, final_trials=ACC_TRIALS, schedule=ACC_SCHEDULE,
-                n=ACC_N, chunk=ACC_CHUNK, shard=False, seed=0)
+                n=ACC_N, stream=StreamSettings(chunk=ACC_CHUNK, shard=False),
+                seed=0)
     assert set(sr.frontier_labels) == set(direct.frontier_labels), (
         f"search missed {set(direct.frontier_labels) - set(sr.frontier_labels)}"
         f", invented {set(sr.frontier_labels) - set(direct.frontier_labels)}")

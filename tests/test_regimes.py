@@ -20,6 +20,7 @@ from repro.montecarlo import build_mask_table, engine, streaming
 from repro.montecarlo.regimes import (MarkovRegimes, RegimeStreamSummary,
                                       gray_failure)
 from repro.montecarlo.scenarios import RunSpec, k_way_race
+from repro.montecarlo.streaming import StreamSettings
 from repro.montecarlo.traces import EmpiricalDelay
 
 KEY = jax.random.PRNGKey(0)
@@ -429,7 +430,9 @@ def test_experiment_from_committed_config(name):
     regime decomposition covers every trial exactly once."""
     from repro.api.experiment import Experiment
     exp = Experiment.from_config(os.path.join(EXAMPLES, name))
-    exp = dataclasses.replace(exp, trials=20_000, chunk=8_192, shard=False)
+    exp = dataclasses.replace(
+        exp, trials=20_000,
+        stream=StreamSettings(chunk=8_192, shard=False))
     r = exp.run("montecarlo")
     assert isinstance(r.stream, RegimeStreamSummary)
     assert int(np.asarray(r.stream.occupancy).sum()) == 20_000
@@ -480,8 +483,8 @@ def test_planner_accepts_serialized_workload_dict():
         == 25.0
 
     p = Planner()
-    base = dict(n=7, family="cardinality", trials=10_000, chunk=8_192,
-                shard=False)
+    base = dict(n=7, family="cardinality", trials=10_000,
+                stream=StreamSettings(chunk=8_192, shard=False))
     with_reg = Workload.race(
         k=2, delta_ms=0.2,
         regimes=gray_failure(7, epoch_trials=512).to_config()).to_dict()
@@ -502,8 +505,9 @@ def test_runspec_is_the_only_knob_path():
     scen = k_way_race(2, 0.25)
     with warnings.catch_warnings():
         warnings.simplefilter("error")       # no warning of any kind
-        new = scen.with_spec(trials=20_000, chunk=8_192,
-                             shard=False).stream(KEY, TABLE)
+        new = scen.with_spec(
+            trials=20_000, stream=StreamSettings(chunk=8_192, shard=False)
+        ).stream(KEY, TABLE)
     assert int(np.asarray(new.n_trials)[0]) == 20_000
     with pytest.raises(TypeError):
         scen.stream(KEY, TABLE, trials=20_000)
@@ -516,13 +520,14 @@ def test_runspec_is_the_only_knob_path():
 
 
 def test_runspec_merged_and_sentinel_k_max():
-    spec = RunSpec().merged(trials=5, chunk=1_024)
-    assert spec.trials == 5 and spec.chunk == 1_024
+    spec = RunSpec().merged(trials=5, stream=StreamSettings(chunk=1_024))
+    assert spec.trials == 5 and spec.stream.chunk == 1_024
     assert spec.merged().trials == 5          # no-op merge keeps values
     # explicit k_max=None (full-sort reference) survives the spec plumbing
-    scen = k_way_race(2, 0.25).with_spec(trials=12_000, chunk=8_192,
-                                      shard=False)
-    full = scen.with_spec(k_max=None).stream(KEY, TABLE)
+    stream = StreamSettings(chunk=8_192, shard=False)
+    scen = k_way_race(2, 0.25).with_spec(trials=12_000, stream=stream)
+    full = scen.with_spec(
+        stream=dataclasses.replace(stream, k_max=None)).stream(KEY, TABLE)
     auto = scen.stream(KEY, TABLE)
     np.testing.assert_array_equal(np.asarray(full.hist),
                                   np.asarray(auto.hist))
@@ -532,8 +537,8 @@ def test_runspec_carries_recovery_rule():
     """``recovery`` rides the spec like every other knob: the entry rate is
     rule-invariant, the streamed histograms differ, and an unknown rule
     raises before any engine work."""
-    scen = k_way_race(2, 0.25).with_spec(trials=20_000, chunk=8_192,
-                                         shard=False)
+    scen = k_way_race(2, 0.25).with_spec(
+        trials=20_000, stream=StreamSettings(chunk=8_192, shard=False))
     sc = scen.stream(KEY, TABLE)
     su = scen.with_spec(recovery="uncoordinated").stream(KEY, TABLE)
     np.testing.assert_array_equal(np.asarray(sc.n_recovery),
@@ -549,7 +554,8 @@ def test_scenario_spec_carries_regimes_through_workload():
                        regimes=gray_failure(11, epoch_trials=1_024))
     scen = wl.scenario(11)
     assert scen.spec.regimes is not None
-    s = scen.with_spec(trials=20_000, chunk=8_192, shard=False).stream(
-        KEY, TABLE)
+    s = scen.with_spec(
+        trials=20_000, stream=StreamSettings(chunk=8_192, shard=False)
+    ).stream(KEY, TABLE)
     assert isinstance(s, RegimeStreamSummary)
     assert int(np.asarray(s.occupancy).sum()) == 20_000

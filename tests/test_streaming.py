@@ -10,9 +10,9 @@ from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.quorum import ExplicitQuorumSystem, QuorumSpec
 from repro.montecarlo import build_mask_table, engine, streaming
-from repro.montecarlo.streaming import (StreamSummary, bucket_index,
-                                        bucket_value, sketch_bins,
-                                        sketch_gamma)
+from repro.montecarlo.streaming import (StreamSettings, StreamSummary,
+                                        bucket_index, bucket_value,
+                                        sketch_bins, sketch_gamma)
 
 KEY = jax.random.PRNGKey(0)
 FFP = QuorumSpec.paper_headline(11)
@@ -503,12 +503,13 @@ def test_experiment_ten_million_trials_fixed_memory():
     measured on a materialized slice of the same workload."""
     from repro.api import Experiment, Workload
     exp = Experiment(systems=[FFP], workload=Workload.conflict_free(),
-                     trials=10_000_000, chunk=262_144,
+                     trials=10_000_000,
+                     stream=streaming.StreamSettings(chunk=262_144),
                      compute_fault_tolerance=False)
     r = exp.run("montecarlo")
     state = r.stream
     assert int(state.n_trials[0]) == 10_000_000
-    assert state.hist.shape == (1, sketch_bins(exp.precision))
+    assert state.hist.shape == (1, sketch_bins(exp.stream.precision))
     # exact reference: a materialized 200k sample of the same distribution
     exact = engine.summarize(engine.fast_path(
         jax.random.PRNGKey(17), build_mask_table([FFP]), n=11,
@@ -516,7 +517,8 @@ def test_experiment_ten_million_trials_fixed_memory():
     for q in ("p50_ms", "p99_ms"):
         got, ref = float(r.summary[q][0]), float(exact[q][0])
         # sketch precision + cross-sample Monte-Carlo noise at 200k
-        assert abs(got - ref) / ref < exp.precision + 0.02, (q, got, ref)
+        assert abs(got - ref) / ref < exp.stream.precision + 0.02, \
+            (q, got, ref)
 
 
 # ---------------------------------------------------------------------------
@@ -673,3 +675,38 @@ def test_resolve_k_sat_auto_on_mixed_table():
     assert "q" not in table
     assert streaming._resolve_k_sat(table, "auto", 11) \
         == engine.saturation_depths(table)
+
+
+# ---------------------------------------------------------------------------
+# StreamSettings: validated at construction, flat keys round-trip
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("flat, error", [
+    ({"chunk": 0}, "chunk must be >= 1"),
+    ({"precision": 0.5}, "precision"),
+    ({"k_max": "deep"}, "k_max must be"),
+    ({"k_max": (9, 3)}, "k_max must be"),
+    ({}, None),
+    ({"chunk": 16_384, "precision": 0.02, "shard": False,
+      "use_kernel": True, "k_max": [9, 3, 7]}, None),
+    ({"chunk": None, "precision": None, "k_max": None}, None),
+])
+def test_stream_settings_validate_and_round_trip(flat, error):
+    """A bad chunk, precision or k_max is refused when the settings are
+    built, not when a stream first runs; a good flat dict lifts over the
+    base (a null chunk/precision keeps it), leaves its other keys alone,
+    and ``to_flat`` / ``from_flat`` round-trip to the same settings."""
+    base = StreamSettings(chunk=8_192, shard=False)
+    if error is not None:
+        with pytest.raises(ValueError, match=error):
+            StreamSettings.from_flat(dict(flat, seed=3), base)
+        return
+    s, rest = StreamSettings.from_flat(dict(flat, seed=3), base)
+    assert rest == {"seed": 3}
+    want = dict(base.to_flat(), **{
+        k: tuple(v) if isinstance(v, list) else v for k, v in flat.items()
+        if v is not None or k == "k_max"})
+    assert s.to_flat() == want
+    assert StreamSettings.from_flat(s.to_flat())[0] == s
+    assert StreamSettings(**s.to_flat()) == s
+    hash(s)                                  # usable as a search key
